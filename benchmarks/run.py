@@ -9,11 +9,11 @@ Prints ``name,us_per_call,derived`` CSV rows. Figures:
   fig8  contention sweep: utility + fair-share slowdown vs oversubscription
   eq1   RAR iteration-time model table (paper §III-3)
   re_ring  mid-slot re-ring (elastic reshard) cost vs the paper's
-           checkpoint-preemption model (spawns 8 XLA host devices)
+           checkpoint-preemption model (spawns 8 virtual CPU devices)
   compress  compressed-ring microbench: f32 ring vs XLA int8 ring vs the
             fused single-ppermute Pallas ring, plus the bf16/fp8 wire
             formats and the bucketed overlap pipeline (exposed-comm +
-            hidden-fraction rows; spawns 8 XLA host devices;
+            hidden-fraction rows; spawns 8 virtual CPU devices;
             wire-bytes + ppermute-count + us/call rows)
   serve     continuous-batching serving engine: tokens/s continuous vs
             static batching under bursty arrivals (one decode-step compile
@@ -297,8 +297,8 @@ def re_ring_cost(full: bool = False) -> None:
 
     repeats = 5 if full else 3
     record_meta("re_ring", repeats=repeats, arch="qwen3-0.6b (reduced)",
-                data_seed=0, devices=8, ring="w8to4", optimizer="sgdm",
-                mode="psum")
+                data_seed=0, devices=8, platform="cpu", ring="w8to4",
+                optimizer="sgdm", mode="psum")
     prog = textwrap.dedent(f"""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -355,6 +355,9 @@ def re_ring_cost(full: bool = False) -> None:
     """)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # a rehearsal on 8 virtual CPU devices: the child never opens the chip,
+    # so it cannot contend with this process (or another) for it
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + (
@@ -370,11 +373,12 @@ def re_ring_cost(full: bool = False) -> None:
         _, name, seconds, extra = line.split(maxsplit=3)
         timed[name] = float(seconds)
         emit(f"re_ring/{name}", float(seconds) * 1e6,
-             f"seconds={float(seconds):.6e};{extra}")
+             f"seconds={float(seconds):.6e};{extra};platform=cpu")
     if "re_ring_w8to4" in timed and "ckpt_preempt_w8to4" in timed:
         ratio = timed["ckpt_preempt_w8to4"] / max(timed["re_ring_w8to4"],
                                                   1e-12)
-        emit("re_ring/preempt_over_re_ring", 0.0, f"ratio={ratio:.3f}")
+        emit("re_ring/preempt_over_re_ring", 0.0,
+             f"ratio={ratio:.3f};platform=cpu")
 
 
 def compress_ring_bench(full: bool = False) -> None:
@@ -404,7 +408,8 @@ def compress_ring_bench(full: bool = False) -> None:
     d = (1 << 22) if full else (1 << 18)
     repeats = 20 if full else 8
     n_buckets = 4
-    record_meta("compress", d=d, repeats=repeats, devices=8, data_seed=0,
+    record_meta("compress", d=d, repeats=repeats, devices=8, platform="cpu",
+                data_seed=0,
                 overlap_n_buckets=n_buckets,
                 overlap_hidden_fraction=(n_buckets - 1) / n_buckets,
                 wire_modes=["int8", "bf16", "fp8"])
@@ -461,6 +466,9 @@ def compress_ring_bench(full: bool = False) -> None:
     """)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # a rehearsal on 8 virtual CPU devices: the child never opens the chip,
+    # so it cannot contend with this process (or another) for it
+    env["JAX_PLATFORMS"] = "cpu"
     src = os.path.join(
         os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src")
     env["PYTHONPATH"] = src + (
@@ -506,16 +514,18 @@ def compress_ring_bench(full: bool = False) -> None:
         wire, msgs = costs[name]
         emit(f"compress/{name}", float(seconds) * 1e6,
              f"wire_bytes_per_worker={wire:.0f};ppermutes={msgs};"
-             f"ppermutes_per_hop={msgs / (2 * (w - 1)):.1f};d={d};w={w}")
+             f"ppermutes_per_hop={msgs / (2 * (w - 1)):.1f};d={d};w={w};"
+             "platform=cpu")
     if "xla_int8_ring" in timed and "fused_int8_ring" in timed:
         speedup = timed["xla_int8_ring"] / max(timed["fused_int8_ring"], 1e-12)
-        emit("compress/fused_over_xla_int8", 0.0, f"speedup={speedup:.3f}")
+        emit("compress/fused_over_xla_int8", 0.0,
+             f"speedup={speedup:.3f};platform=cpu")
     if "overlap_int8_ring" in timed:
         h = (n_buckets - 1) / n_buckets
         total_us = timed["overlap_int8_ring"] * 1e6
         emit("compress/overlap_exposed_comm", total_us * (1.0 - h),
              f"hidden_fraction={h:.3f};n_buckets={n_buckets};"
-             f"total_comm_us={total_us:.1f};d={d};w={w}")
+             f"total_comm_us={total_us:.1f};d={d};w={w};platform=cpu")
 
 
 class _TimedScheduler:

@@ -14,10 +14,12 @@ from repro.cluster.metrics import csv_lines, summarize
 from repro.cluster.trace import JobTraceConfig, generate_jobs
 from repro.core.problem import DDLJSInstance
 from repro.core.rar_model import profile_from_arch, optimal_worker_count
+from repro.launch.runtime import enable_compile_cache
 from repro.sched import FaultConfig, OnlineDriver, registry
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1) Eq. (1) in isolation: the per-iteration time model for a 1.2B job
     prof = profile_from_arch(n_params=1.2e9, tokens_per_batch=4096 * 8)
     print("== Eq. (1): RAR iteration time vs ring size ==")

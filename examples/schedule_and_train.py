@@ -25,6 +25,7 @@ from repro.core.rar_model import profile_from_arch
 from repro.core.utility import sqrt_utility
 from repro.configs import get_arch
 from repro.data.pipeline import SyntheticTokens
+from repro.launch.runtime import enable_compile_cache
 from repro.models.model import build_model
 from repro.sched import (
     ContentionConfig,
@@ -68,6 +69,7 @@ def make_jobs():
 
 
 def main() -> None:
+    enable_compile_cache()
     # 1-2 GPUs per server: rings must span servers and share uplinks, so the
     # contention re-pricing actually engages (colocated rings never contend)
     graph = make_fat_tree(n_servers=4, n_racks=2, n_core=1,

@@ -31,6 +31,7 @@ from repro.cluster.topology import Link, Server, SubstrateGraph
 from repro.configs import get_arch
 from repro.core.problem import DDLJSInstance, Job
 from repro.core.utility import sqrt_utility
+from repro.launch.runtime import enable_compile_cache
 from repro.launch.serve import (
     Request,
     ServingEngine,
@@ -131,6 +132,7 @@ def coschedule_demo() -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     engine_demo()
     coschedule_demo()
 

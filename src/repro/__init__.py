@@ -1,10 +1,1 @@
-"""GADGET reproduction: ring-all-reduce scheduling + executable RAR training.
-
-Importing the package installs the jax version-compat shims (idempotent);
-``src/sitecustomize.py`` additionally covers processes that touch jax
-before importing ``repro`` (e.g. the multi-device test subprocesses).
-"""
-
-from repro import compat as _compat
-
-_compat.install()
+"""GADGET reproduction: ring-all-reduce scheduling + executable RAR training."""

@@ -197,7 +197,7 @@ def _ppermute_bytes(sites: Sequence[CollectiveSite]) -> int:
 def trace_ring_variant(variant, w: int, d: int):
     """Trace one registered collective at world size w on a d-element
     gradient; returns the closed jaxpr."""
-    mesh = AbstractMesh(((AXIS, w),))
+    mesh = AbstractMesh((w,), (AXIS,))
     fn = jax.shard_map(variant.build(AXIS), mesh=mesh, in_specs=P(AXIS),
                        out_specs=P(AXIS), check_vma=False)
     return jax.make_jaxpr(fn)(jax.ShapeDtypeStruct((w * d,), jnp.float32))
@@ -249,7 +249,7 @@ def trace_train_step(mode: str, w: int, *, per_worker_batch: int = 2,
     model = _VerifierModel()
     optimizer = make_optimizer(optimizer_name)
     step = make_ring_train_step(model, optimizer, AXIS, lr=1e-2, mode=mode)
-    mesh = AbstractMesh(((AXIS, w),))
+    mesh = AbstractMesh((w,), (AXIS,))
     smapped = jax.shard_map(step, mesh=mesh,
                             in_specs=(P(), P(), P(AXIS)),
                             out_specs=(P(), P(), P()), check_vma=False)

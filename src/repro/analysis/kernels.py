@@ -5,15 +5,17 @@ of the fused quantized-ring kernels *without a TPU* — the checks are pure
 arithmetic over the same constants the kernels use (imported from
 ``quant_ring``, not copied, so they cannot drift):
 
-  * **tile divisibility** — the resolved ``rows_per_tile`` must divide
-    ``n_blocks`` (an explicit override that does not is rejected, exactly as
-    ``_rows_per_tile`` rejects it at trace time);
+  * **tile shape** — an explicit ``rows_per_tile`` override must divide
+    ``n_blocks`` and be ``n_blocks`` or a multiple of ``ROW_ALIGN`` (one
+    that is not is rejected, exactly as ``_rows_per_tile`` rejects it at
+    trace time); the resolver's own choice may leave a ragged last tile;
   * **tile budget** — the per-tile VMEM working set
     (``rows * block * bytes_per_elem + rows * SCALE_BYTES`` for the scale
     rows) must fit ``_TILE_BUDGET_BYTES``. ``_rows_per_tile`` itself does
-    NOT enforce this when a single sub-block row already exceeds the budget
-    (``block * bytes_per_elem > _TILE_BUDGET_BYTES`` resolves to
-    ``rows=1`` and over-commits VMEM) — the checker closes that gap;
+    NOT enforce this when the smallest legal tile already exceeds the
+    budget (a huge ``block`` resolves to ``ROW_ALIGN`` rows, or to the
+    whole ``n_blocks`` below that, and over-commits VMEM) — the checker
+    closes that gap;
   * **VMEM bound** — the double-buffered working set (Pallas pipelines the
     next tile's copy while the current one computes) must fit the ~16 MB
     VMEM of a TPU core;
@@ -115,7 +117,8 @@ def check_spec(spec: KernelSpec) -> CheckResult:
     rows: Optional[int]
     try:
         # the real resolver — an explicit override that does not divide
-        # n_blocks raises here exactly as it would at pallas_call trace time
+        # n_blocks, or is not a legal TPU tile height, raises here exactly
+        # as it would at pallas_call trace time
         rows = _rows_per_tile(spec.n_blocks, spec.block, spec.rows_per_tile,
                               bytes_per_elem=bpe)
     except ValueError as exc:

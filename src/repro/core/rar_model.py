@@ -10,7 +10,7 @@ All quantities use SI base units: d in parameters (grad elements), b in
 elements/second (bandwidth divided by element width), G in elements/second
 reduction throughput, times in seconds.
 
-The functions are plain-float *and* jnp-compatible so the scheduler can run
+The functions accept plain floats *and* jnp arrays, so the scheduler can run
 vectorized sweeps over (job, worker-count) grids on device.
 """
 

@@ -41,9 +41,26 @@ def _ring_perm(w: int, reverse: bool = False):
     return [(i, (i + 1) % w) for i in range(w)]
 
 
+def flatten_for_ring(x: jax.Array) -> jax.Array:
+    """``x`` as a flat vector, behind an optimization barrier.
+
+    The rings slice a flattened leaf by ring position. Left to itself,
+    XLA's TPU compiler folds the flattening reshape of a multi-dimensional
+    leaf into those dynamic slices, and its compile time then grows with
+    the leaf's row count: minutes for qwen3-0.6b's 151936 x 1024
+    embedding. The barrier keeps the relayout a plain copy.
+    """
+    return lax.optimization_barrier(x.reshape(-1))
+
+
+def unflatten_from_ring(flat: jax.Array, shape) -> jax.Array:
+    """Inverse of :func:`flatten_for_ring`: ``flat`` in ``shape``."""
+    return lax.optimization_barrier(flat).reshape(shape)
+
+
 def _as_chunks(x: jax.Array, w: int) -> Tuple[jax.Array, int]:
     """Flatten and pad x so it splits into w equal ring chunks."""
-    flat = x.reshape(-1)
+    flat = flatten_for_ring(x)
     pad = (-flat.size) % w
     if pad:
         flat = jnp.pad(flat, (0, pad))
@@ -116,7 +133,10 @@ def _ring_all_reduce_flat(x: jax.Array, axis_name: str,
 def ring_all_reduce(x: jax.Array, axis_name: str, *,
                     reverse: bool = False) -> jax.Array:
     """Paper-faithful ring all-reduce: 2(w-1) ppermute steps, sum semantics."""
-    return _ring_all_reduce_flat(x, axis_name, reverse).reshape(x.shape)
+    if lax.axis_size(axis_name) == 1:
+        return x
+    return unflatten_from_ring(_ring_all_reduce_flat(x, axis_name, reverse),
+                               x.shape)
 
 
 def bidirectional_ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
@@ -126,11 +146,11 @@ def bidirectional_ring_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:
     w = lax.axis_size(axis_name)
     if w == 1:
         return x
-    flat = x.reshape(-1)
+    flat = flatten_for_ring(x)
     half = (flat.size + 1) // 2
     fwd = _ring_all_reduce_flat(flat[:half], axis_name, reverse=False)
     bwd = _ring_all_reduce_flat(flat[half:], axis_name, reverse=True)
-    return jnp.concatenate([fwd, bwd]).reshape(x.shape)
+    return unflatten_from_ring(jnp.concatenate([fwd, bwd]), x.shape)
 
 
 def psum_all_reduce(x: jax.Array, axis_name: str) -> jax.Array:

@@ -60,7 +60,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.dist.collectives import _all_gather_chunks, _as_chunks, _ring_perm
+from repro.dist.collectives import (
+    _all_gather_chunks,
+    _as_chunks,
+    _ring_perm,
+    flatten_for_ring,
+    unflatten_from_ring,
+)
 from repro.kernels.quant_ring import (
     FP8_DTYPE,
     SCALE_BYTES,  # noqa: F401  (re-export: the wire accounting's name for it)
@@ -72,6 +78,7 @@ from repro.kernels.quant_ring import (
     hop_message_layout,
     quantize_pack_pallas,
 )
+from repro.kernels.ops import interpret_mode
 
 QMAX = 127.0  # symmetric int8 range
 
@@ -107,9 +114,10 @@ def quantization_error(x: jax.Array) -> jax.Array:
 
 
 def _interpret_default(interpret: Optional[bool]) -> bool:
-    """Pallas kernels compile natively on TPU, interpret elsewhere."""
+    """An explicit ``interpret`` wins; otherwise the repo's backend rule
+    (:func:`repro.kernels.ops.interpret_mode`)."""
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return interpret_mode()
     return bool(interpret)
 
 
@@ -207,15 +215,17 @@ def _xla_ring_all_reduce(x: jax.Array, axis_name: str,
     # verbatim (each chunk pays exactly one gather-phase quantization).
     own = (idx + 1) % w
     q_own, s_own = quantize(jnp.take(chunks, own, axis=0))
-    qchunks = jnp.zeros(chunks.shape, jnp.int8).at[own].set(q_own)
-    scales = jnp.zeros((w,), jnp.float32).at[own].set(s_own)
+    qchunks = lax.dynamic_update_index_in_dim(
+        jnp.zeros(chunks.shape, jnp.int8), q_own, own, 0)
+    scales = lax.dynamic_update_index_in_dim(
+        jnp.zeros((w,), jnp.float32), s_own, own, 0)
     qchunks = _all_gather_chunks(qchunks, axis_name, idx, perm)
     scales = _all_gather_chunks(scales[:, None], axis_name, idx, perm)[:, 0]
 
     flat = (qchunks.astype(jnp.float32) * scales[:, None]).reshape(-1)
     if pad:
         flat = flat[: flat.size - pad]
-    return flat.reshape(x.shape).astype(x.dtype)
+    return unflatten_from_ring(flat.astype(x.dtype), x.shape)
 
 
 def _fused_ring_all_reduce(
@@ -232,7 +242,7 @@ def _fused_ring_all_reduce(
     w = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = _ring_perm(w)
-    flat = x.reshape(-1).astype(jnp.float32)
+    flat = flatten_for_ring(x).astype(jnp.float32)
     c_pad, nb, pad = _fused_chunk_layout(flat.size, w, block)
     if pad:
         flat = jnp.pad(flat, (0, pad))
@@ -275,30 +285,39 @@ def _fused_ring_all_reduce(
     # async backend (and each chunk still pays exactly one gather-phase
     # quantization; the owner reads back its own quantized payload so every
     # worker ends with bit-identical values).
-    own = (idx + 1) % w
     send = quant_pack(reduced_own)
     msgs = [send]
-    chunk_ids = [own]
     for s in range(w - 1):
         recv = lax.ppermute(send, axis_name, perm)
         msgs.append(recv)
-        chunk_ids.append((idx - s) % w)
         send = recv
-    stacked = jnp.stack(msgs)                       # (w, message)
-    q_all = stacked[:, : nb * b].reshape(w * nb, b)
-    if jnp.dtype(wire_dtype) != jnp.dtype(jnp.int8):
-        q_all = lax.bitcast_convert_type(q_all, wire_dtype)
-    scales_all = lax.bitcast_convert_type(
-        stacked[:, nb * b:].reshape(w * nb, SCALE_BYTES), jnp.float32)
+    # unpack message by message: a (w, message) stack sliced and reshaped
+    # to (w * nb, b) compiles in time linear in the payload on TPU (minutes
+    # at full width), one 1-D -> 2-D reshape per message does not
+    parts = [unpack_hop_message(m, nb, b, wire_dtype) for m in msgs]
+    q_all = jnp.concatenate([q for q, _ in parts])
+    scales_all = jnp.concatenate([sc for _, sc in parts])
     deq = dequant_accumulate_pallas(q_all, scales_all, None,
                                     interpret=interpret)
-    out = jnp.zeros((w, nb, b), jnp.float32)
-    out = out.at[jnp.stack(chunk_ids)].set(deq.reshape(w, nb, b))
+    out = _gathered_chunk_order(deq.reshape(w, nb, b), idx, w)
 
     flat = out.reshape(-1)
     if pad:
         flat = flat[: flat.size - pad]
-    return flat.reshape(x.shape).astype(x.dtype)
+    return unflatten_from_ring(flat.astype(x.dtype), x.shape)
+
+
+def _gathered_chunk_order(msgs: jax.Array, idx: jax.Array,
+                          w: int) -> jax.Array:
+    """Reorder the Share-Only phase's ``(w, ...)`` messages by chunk index.
+
+    Message ``k`` carries chunk ``(idx + 1 - k) % w`` (the owned chunk
+    first, then one chunk further back per hop), so chunk ``c`` is message
+    ``(idx + 1 - c) % w``. A gather, not a scatter into zeros: the result
+    is varying over the ring axis either way, and the gather needs no
+    unvarying base array.
+    """
+    return jnp.take(msgs, (idx + 1 - jnp.arange(w)) % w, axis=0)
 
 
 def _bf16_fused_ring_all_reduce(
@@ -316,7 +335,7 @@ def _bf16_fused_ring_all_reduce(
     w = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     perm = _ring_perm(w)
-    flat = x.reshape(-1).astype(jnp.float32)
+    flat = flatten_for_ring(x).astype(jnp.float32)
     c_pad, nb, pad = _fused_chunk_layout(flat.size, w, block)
     if pad:
         flat = jnp.pad(flat, (0, pad))
@@ -342,24 +361,20 @@ def _bf16_fused_ring_all_reduce(
 
     # Share-Only: downcast the owned reduced chunk once, forward verbatim,
     # upcast every gathered chunk in one batched kernel call.
-    own = (idx + 1) % w
     send = cast_pack(reduced_own)
     msgs = [send]
-    chunk_ids = [own]
     for s in range(w - 1):
         recv = lax.ppermute(send, axis_name, perm)
         msgs.append(recv)
-        chunk_ids.append((idx - s) % w)
         send = recv
-    stacked = jnp.stack(msgs).reshape(w * nb, b)    # (w, message) -> blocks
+    stacked = jnp.concatenate([m.reshape(nb, b) for m in msgs])
     deq = bf16_accumulate_pallas(stacked, None, interpret=interpret)
-    out = jnp.zeros((w, nb, b), jnp.float32)
-    out = out.at[jnp.stack(chunk_ids)].set(deq.reshape(w, nb, b))
+    out = _gathered_chunk_order(deq.reshape(w, nb, b), idx, w)
 
     flat = out.reshape(-1)
     if pad:
         flat = flat[: flat.size - pad]
-    return flat.reshape(x.shape).astype(x.dtype)
+    return unflatten_from_ring(flat.astype(x.dtype), x.shape)
 
 
 # wire-format name -> quantized payload dtype (None = trailer-free bf16)
@@ -446,14 +461,14 @@ def ef_compressed_all_reduce(
     if fused:
         interp = _interpret_default(interpret)
         c_pad, nb, pad = _fused_chunk_layout(corrected.size, w, block)
-        flat = corrected.reshape(-1)
+        flat = flatten_for_ring(corrected)
         if pad:
             flat = jnp.pad(flat, (0, pad))
         blocks2d = flat.reshape(w * nb, c_pad // nb)
         q, scales = quantize_pack_pallas(blocks2d, interpret=interp)
         deq = dequant_accumulate_pallas(q, scales, None, interpret=interp)
-        deq_flat = deq.reshape(-1)[: corrected.size]
-        compressed = deq_flat.reshape(corrected.shape)
+        compressed = unflatten_from_ring(deq.reshape(-1)[: corrected.size],
+                                         corrected.shape)
         idx = lax.axis_index(axis_name)
         first = pack_hop_message(
             lax.dynamic_slice_in_dim(q, idx * nb, nb, axis=0),

@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro.dist.collectives import flatten_for_ring, unflatten_from_ring
+
 
 def microbatch_grads(loss_fn: Callable, params, batch,
                      n_microbatches: int = 1) -> Tuple[jax.Array, Any]:
@@ -133,13 +135,13 @@ def segmented_ring_reduce(x: jax.Array, ring: Callable[[jax.Array], jax.Array],
                           n_segments: int) -> jax.Array:
     """Reduce a flat array as ``n_segments`` contiguous even segments, each
     through its own ``ring`` call (one ppermute chain per segment)."""
-    flat = x.reshape(-1)
+    flat = flatten_for_ring(x)
     parts = []
     off = 0
     for seg in even_bucket_sizes(flat.size, n_segments):
         parts.append(ring(flat[off: off + seg]))
         off += seg
-    return jnp.concatenate(parts).reshape(x.shape)
+    return unflatten_from_ring(jnp.concatenate(parts), x.shape)
 
 
 # ---------------------------------------------------------------------------
@@ -159,12 +161,14 @@ def _bucketed_reduce(grads, n_buckets: int, reduce_flat: Callable,
         for i in bucket:
             by_dtype.setdefault(leaves[i].dtype, []).append(i)
         for dtype, idxs in by_dtype.items():
-            flat = jnp.concatenate([leaves[i].reshape(-1) for i in idxs])
+            flat = jnp.concatenate([flatten_for_ring(leaves[i])
+                                    for i in idxs])
             red = reduce_flat(flat)
             off = 0
             for i in idxs:
                 n = leaves[i].size
-                out[i] = red[off: off + n].reshape(leaves[i].shape)
+                out[i] = unflatten_from_ring(red[off: off + n],
+                                             leaves[i].shape)
                 off += n
     return jax.tree.unflatten(treedef, out)
 

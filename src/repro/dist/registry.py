@@ -117,7 +117,7 @@ class RingVariant:
 def _ef_build(axis_name: str, *, fused: bool) -> Callable:
     def run(g: jax.Array) -> jax.Array:
         reduced, _ = ef_compressed_all_reduce(
-            g, jnp.zeros_like(g), axis_name, fused=fused, interpret=True)
+            g, jnp.zeros_like(g), axis_name, fused=fused)
         return reduced
     return run
 
@@ -164,26 +164,25 @@ RING_VARIANTS: Tuple[RingVariant, ...] = (
         source="src/repro/dist/overlap.py"),
     RingVariant(
         name="int8",
-        build=lambda ax: partial(compressed_ring_all_reduce, axis_name=ax,
-                                 interpret=True),
+        build=lambda ax: partial(compressed_ring_all_reduce, axis_name=ax),
         compression="int8",
         source="src/repro/dist/compression.py"),
     RingVariant(
         name="int8-fused",
         build=lambda ax: partial(compressed_ring_all_reduce, axis_name=ax,
-                                 fused=True, interpret=True),
+                                 fused=True),
         compression="int8-fused",
         source="src/repro/dist/compression.py"),
     RingVariant(
         name="bf16-fused",
         build=lambda ax: partial(fused_wire_all_reduce, axis_name=ax,
-                                 wire="bf16", interpret=True),
+                                 wire="bf16"),
         compression="bf16-fused",
         source="src/repro/dist/compression.py"),
     RingVariant(
         name="fp8-fused",
         build=lambda ax: partial(fused_wire_all_reduce, axis_name=ax,
-                                 wire="fp8", interpret=True),
+                                 wire="fp8"),
         compression="fp8-fused",
         source="src/repro/dist/compression.py"),
     RingVariant(

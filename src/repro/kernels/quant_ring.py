@@ -31,9 +31,18 @@ The grid walks row tiles with Pallas' automatic input double-buffering:
 while tile ``k`` is being quantized/accumulated in VMEM, tile ``k+1``'s
 HBM->VMEM copy is already in flight — the intra-message half of the hop
 overlap that ``repro.dist.compression`` builds its double-buffered hop
-schedule on. Tiles default to the largest divisor of ``n_blocks`` whose
-f32+int8 working set stays within ``_TILE_BUDGET_BYTES`` (a conservative
-slice of the ~16 MB VMEM, so in/out tiles double-buffer comfortably).
+schedule on. A tile is the whole ``n_blocks`` dimension when it fits
+``_TILE_BUDGET_BYTES`` (a conservative slice of the ~16 MB VMEM, so in/out
+tiles double-buffer comfortably); otherwise it is a multiple of
+``ROW_ALIGN`` rows, the TPU's native int8 tile height. A dividing tile is
+preferred; when ``n_blocks`` has no aligned divisor under the budget the
+grid is ``cdiv(n_blocks, rows)`` and the last tile is ragged. Every row is
+quantized on its own, so the rows past the end of a ragged tile never touch
+a real row, and their writes are dropped.
+
+Scales live in VMEM as an ``(n_blocks, 1)`` column, so a scale tile is
+``(rows, 1)``: row-aligned like its payload tile, and whole in the lane
+dimension. The public functions take and return ``(n_blocks,)`` scales.
 
 Three wire dtypes share the pipeline:
 
@@ -62,6 +71,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 QMAX = 127.0      # symmetric int8 range
 FP8_MAX = 448.0   # float8_e4m3fn finfo max (no inf: overflow saturates here)
@@ -131,34 +141,85 @@ def hop_message_layout(chunk_elems: int, *, block: int) -> HopMessageLayout:
 # side) double-buffered must fit VMEM with headroom
 _TILE_BUDGET_BYTES = 2 * 1024 * 1024
 
+# row (second-minor) alignment of a tile that is not the whole array: 32
+# rows is one native int8/fp8 tile, and a multiple of the bf16 (16) and f32
+# (8) tile heights, so one alignment serves every operand of every kernel
+ROW_ALIGN = 32
+
 
 def _rows_per_tile(nb: int, block: int, rows: Optional[int],
                    bytes_per_elem: int) -> int:
-    """Largest divisor of ``nb`` whose tile fits the VMEM budget (or the
-    validated explicit ``rows`` override)."""
+    """Rows of one tile: the whole ``nb`` when it fits the VMEM budget,
+    else the largest ``ROW_ALIGN`` multiple under the budget, preferring
+    one that divides ``nb`` (the grid is ``cdiv(nb, rows)`` either way).
+
+    An explicit ``rows`` override must divide ``nb`` and be ``nb`` itself
+    or a multiple of ``ROW_ALIGN``: the TPU compiler refuses any other
+    tile height.
+    """
     if rows is not None:
         if nb % rows:
             raise ValueError(f"rows_per_tile={rows} must divide n_blocks={nb}")
+        if rows != nb and rows % ROW_ALIGN:
+            raise ValueError(f"rows_per_tile={rows} must be n_blocks={nb} "
+                             f"or a multiple of {ROW_ALIGN}")
         return int(rows)
-    cap = max(1, _TILE_BUDGET_BYTES // max(block * bytes_per_elem, 1))
-    r = min(nb, cap)
-    while nb % r:
-        r -= 1
-    return r
+    cap = _TILE_BUDGET_BYTES // max(block * bytes_per_elem, 1)
+    if nb <= max(cap, ROW_ALIGN):
+        return nb
+    top = max(ROW_ALIGN, cap - cap % ROW_ALIGN)
+    for r in range(top, 0, -ROW_ALIGN):
+        if nb % r == 0:
+            return r
+    return top
+
+
+def _tiled_call(kernel, operands, out_shapes, *, rows: int,
+                interpret: bool):
+    """``pallas_call`` over row tiles of ``(n_blocks, block)`` operands.
+
+    ``operands`` and ``out_shapes`` hold ``(n_blocks, block)`` payloads and
+    ``(n_blocks, 1)`` scale columns; each gets a ``(rows, block)`` or
+    ``(rows, 1)`` tile. Outputs carry the operands' varying manual axes, so
+    the call type-checks inside a ``shard_map`` with ``check_vma=True``.
+    Interpret mode runs the TPU interpreter: the generic HLO interpreter
+    refuses operands that vary over a manual mesh axis.
+    """
+    nb = operands[0].shape[0]
+    vma = frozenset().union(*(jax.typeof(x).vma for x in operands))
+
+    def spec(shape):
+        return pl.BlockSpec((rows, shape[1]), lambda i: (i, 0))
+
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(nb, rows),),
+        in_specs=[spec(x.shape) for x in operands],
+        out_specs=[spec(o.shape) for o in out_shapes],
+        out_shape=[jax.ShapeDtypeStruct(o.shape, o.dtype, vma=vma)
+                   for o in out_shapes],
+        interpret=pltpu.InterpretParams() if interpret else False,
+    )(*operands)
 
 
 def _quantize_rows(y: jax.Array, qmax: float, wire_dtype):
-    """Per-row amax scale + quantized payload of a 2-D f32 tile.
+    """Per-row amax scale ``(rows, 1)`` + quantized payload of a 2-D f32
+    tile.
 
     Integer wire dtypes round to the nearest step; float wire dtypes (fp8)
     keep the scaled value's mantissa and let the dtype cast do the rounding.
     """
-    amax = jnp.max(jnp.abs(y), axis=-1)
+    amax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
     scale = jnp.where(amax > 0, amax / qmax, 1.0)
-    v = y / scale[:, None]
+    v = y / scale
     if jnp.issubdtype(jnp.dtype(wire_dtype), jnp.integer):
         v = jnp.round(v)
     return jnp.clip(v, -qmax, qmax).astype(wire_dtype), scale
+
+
+def _scale_column(scales: jax.Array) -> jax.Array:
+    """``(n_blocks,)`` scales as the kernels' ``(n_blocks, 1)`` column."""
+    return scales.astype(jnp.float32).reshape(-1, 1)
 
 
 def _make_quantize_pack_kernel(qmax: float, wire_dtype):
@@ -185,23 +246,19 @@ def quantize_pack_pallas(x: jax.Array, *, interpret: bool = False,
     qmax = wire_qmax(wire_dtype)
     nb, block = x.shape
     rows = _rows_per_tile(nb, block, rows_per_tile, bytes_per_elem=5)
-    return pl.pallas_call(
-        _make_quantize_pack_kernel(qmax, wire_dtype),
-        grid=(nb // rows,),
-        in_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0))],
-        out_specs=[pl.BlockSpec((rows, block), lambda i: (i, 0)),
-                   pl.BlockSpec((rows,), lambda i: (i,))],
-        out_shape=[jax.ShapeDtypeStruct((nb, block), jnp.dtype(wire_dtype)),
-                   jax.ShapeDtypeStruct((nb,), jnp.float32)],
-        interpret=interpret,
-    )(x)
+    q, scales = _tiled_call(
+        _make_quantize_pack_kernel(qmax, wire_dtype), [x],
+        [jax.ShapeDtypeStruct((nb, block), jnp.dtype(wire_dtype)),
+         jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
+        rows=rows, interpret=interpret)
+    return q, scales.reshape(nb)
 
 
 def _make_dequant_add_quantize_kernel(qmax: float, wire_dtype):
     def kernel(q_ref, scale_ref, acc_ref, q_out, s_out):
         """One tile of the steady-state hop: requantize(acc + q * scale)."""
         y = (acc_ref[...].astype(jnp.float32)
-             + q_ref[...].astype(jnp.float32) * scale_ref[...][:, None])
+             + q_ref[...].astype(jnp.float32) * scale_ref[...])
         q, scale = _quantize_rows(y, qmax, wire_dtype)
         q_out[...] = q
         s_out[...] = scale
@@ -221,29 +278,24 @@ def dequant_add_quantize_pallas(q: jax.Array, scales: jax.Array,
     qmax = wire_qmax(wire_dtype)
     nb, block = q.shape
     rows = _rows_per_tile(nb, block, rows_per_tile, bytes_per_elem=6)
-    payload_spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
-    scale_spec = pl.BlockSpec((rows,), lambda i: (i,))
-    return pl.pallas_call(
+    q2, s2 = _tiled_call(
         _make_dequant_add_quantize_kernel(qmax, wire_dtype),
-        grid=(nb // rows,),
-        in_specs=[payload_spec, scale_spec, payload_spec],
-        out_specs=[payload_spec, scale_spec],
-        out_shape=[jax.ShapeDtypeStruct((nb, block), wire_dtype),
-                   jax.ShapeDtypeStruct((nb,), jnp.float32)],
-        interpret=interpret,
-    )(q, scales, acc)
+        [q, _scale_column(scales), acc],
+        [jax.ShapeDtypeStruct((nb, block), wire_dtype),
+         jax.ShapeDtypeStruct((nb, 1), jnp.float32)],
+        rows=rows, interpret=interpret)
+    return q2, s2.reshape(nb)
 
 
 def _dequant_accumulate_kernel(q_ref, scale_ref, acc_ref, out_ref):
     """One tile: out = acc + q * scale, f32 intermediate stays in VMEM."""
     q = q_ref[...].astype(jnp.float32)
-    out_ref[...] = (acc_ref[...].astype(jnp.float32)
-                    + q * scale_ref[...][:, None])
+    out_ref[...] = acc_ref[...].astype(jnp.float32) + q * scale_ref[...]
 
 
 def _dequant_kernel(q_ref, scale_ref, out_ref):
     """One tile: out = q * scale (Share-Only unpack, no accumulator)."""
-    out_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[...][:, None]
+    out_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[...]
 
 
 def dequant_accumulate_pallas(q: jax.Array, scales: jax.Array,
@@ -259,27 +311,15 @@ def dequant_accumulate_pallas(q: jax.Array, scales: jax.Array,
     nb, block = q.shape
     rows = _rows_per_tile(nb, block, rows_per_tile,
                           bytes_per_elem=9 if acc is not None else 5)
-    payload_spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
-    scale_spec = pl.BlockSpec((rows,), lambda i: (i,))
-    out_spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((nb, block), jnp.float32)
-    if acc is None:
-        return pl.pallas_call(
-            _dequant_kernel,
-            grid=(nb // rows,),
-            in_specs=[payload_spec, scale_spec],
-            out_specs=out_spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(q, scales)
-    return pl.pallas_call(
-        _dequant_accumulate_kernel,
-        grid=(nb // rows,),
-        in_specs=[payload_spec, scale_spec, payload_spec],
-        out_specs=out_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(q, scales, acc)
+    operands = [q, _scale_column(scales)]
+    kernel = _dequant_kernel
+    if acc is not None:
+        operands.append(acc)
+        kernel = _dequant_accumulate_kernel
+    (out,) = _tiled_call(kernel, operands,
+                         [jax.ShapeDtypeStruct((nb, block), jnp.float32)],
+                         rows=rows, interpret=interpret)
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -299,15 +339,10 @@ def cast_pack_bf16_pallas(x: jax.Array, *, interpret: bool = False,
     message is the bare payload)."""
     nb, block = x.shape
     rows = _rows_per_tile(nb, block, rows_per_tile, bytes_per_elem=6)
-    spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
-    return pl.pallas_call(
-        _cast_bf16_kernel,
-        grid=(nb // rows,),
-        in_specs=[spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.bfloat16),
-        interpret=interpret,
-    )(x)
+    (out,) = _tiled_call(_cast_bf16_kernel, [x],
+                         [jax.ShapeDtypeStruct((nb, block), jnp.bfloat16)],
+                         rows=rows, interpret=interpret)
+    return out
 
 
 def _bf16_add_cast_kernel(recv_ref, acc_ref, out_ref):
@@ -324,15 +359,10 @@ def bf16_add_cast_pallas(recv: jax.Array, acc: jax.Array, *,
     the next hop's bf16 payload — one pass, no HBM f32 intermediate."""
     nb, block = recv.shape
     rows = _rows_per_tile(nb, block, rows_per_tile, bytes_per_elem=8)
-    spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
-    return pl.pallas_call(
-        _bf16_add_cast_kernel,
-        grid=(nb // rows,),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        out_shape=jax.ShapeDtypeStruct((nb, block), jnp.bfloat16),
-        interpret=interpret,
-    )(recv, acc)
+    (out,) = _tiled_call(_bf16_add_cast_kernel, [recv, acc],
+                         [jax.ShapeDtypeStruct((nb, block), jnp.bfloat16)],
+                         rows=rows, interpret=interpret)
+    return out
 
 
 def _bf16_accumulate_kernel(recv_ref, acc_ref, out_ref):
@@ -356,22 +386,12 @@ def bf16_accumulate_pallas(recv: jax.Array,
     nb, block = recv.shape
     rows = _rows_per_tile(nb, block, rows_per_tile,
                           bytes_per_elem=10 if acc is not None else 6)
-    spec = pl.BlockSpec((rows, block), lambda i: (i, 0))
-    out_shape = jax.ShapeDtypeStruct((nb, block), jnp.float32)
-    if acc is None:
-        return pl.pallas_call(
-            _bf16_upcast_kernel,
-            grid=(nb // rows,),
-            in_specs=[spec],
-            out_specs=spec,
-            out_shape=out_shape,
-            interpret=interpret,
-        )(recv)
-    return pl.pallas_call(
-        _bf16_accumulate_kernel,
-        grid=(nb // rows,),
-        in_specs=[spec, spec],
-        out_specs=spec,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(recv, acc)
+    operands = [recv]
+    kernel = _bf16_upcast_kernel
+    if acc is not None:
+        operands.append(acc)
+        kernel = _bf16_accumulate_kernel
+    (out,) = _tiled_call(kernel, operands,
+                         [jax.ShapeDtypeStruct((nb, block), jnp.float32)],
+                         rows=rows, interpret=interpret)
+    return out

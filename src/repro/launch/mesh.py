@@ -9,6 +9,14 @@ init; smoke tests and benches see 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes GSPMD partitions: the dry-run steers layouts with
+    ``with_sharding_constraint``, which refuses the Explicit axes
+    ``jax.make_mesh`` gives by default."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,11 +28,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_dev_mesh(n_data: int = 2, n_model: int = 4, *, multi_pod: bool = False):
     """Small mesh over host devices for integration tests."""
     if multi_pod:
-        return jax.make_mesh((2, n_data, n_model), ("pod", "data", "model"))
-    return jax.make_mesh((n_data, n_model), ("data", "model"))
+        return _auto_mesh((2, n_data, n_model), ("pod", "data", "model"))
+    return _auto_mesh((n_data, n_model), ("data", "model"))

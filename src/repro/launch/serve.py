@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, list_archs
+from repro.launch.runtime import device_summary, enable_compile_cache
 from repro.models.model import (
     build_model,
     cache_lane,
@@ -485,15 +486,23 @@ def audit_serving_engine(engine: ServingEngine) -> List[str]:
 def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True, choices=list_archs())
+    p.add_argument("--reduced", action="store_true",
+                   help="serve the reduced (CPU-sized) config in float32 "
+                        "(default: published widths, bf16 params)")
     p.add_argument("--batch", type=int, default=4)
     p.add_argument("--prompt-len", type=int, default=8)
     p.add_argument("--max-new", type=int, default=16)
     p.add_argument("--chunk", type=int, default=8)
     args = p.parse_args()
 
-    cfg = get_arch(args.arch).reduced()
+    enable_compile_cache()
+    print(json.dumps({"device": device_summary()}), flush=True)
+    cfg = get_arch(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+    params = model.init(jax.random.PRNGKey(0),
+                        dtype=jnp.float32 if args.reduced else jnp.bfloat16)
     rng = np.random.default_rng(1)
     engine = ServingEngine(model, params, max_batch=args.batch,
                            max_seq=args.prompt_len + args.max_new,

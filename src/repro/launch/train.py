@@ -8,21 +8,24 @@ process groups are wired by the launcher environment (GKE/XPK-style).
 Examples:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
   python -m repro.launch.train --arch qwen3-0.6b --reduced --steps 50 --dp 8
+
+  # published widths, bf16 params, on one chip
+  python -m repro.launch.train --arch qwen3-0.6b --steps 20 --seq 1024
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import os
 import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.configs import get_arch, list_archs
 from repro.data.pipeline import SyntheticTokens
+from repro.dist.registry import STEP_MODES
+from repro.launch.runtime import device_summary, enable_compile_cache
 from repro.models.model import build_model
 from repro.training.elastic import ElasticTrainer, SlotPlan
 from repro.training.optimizer import make_optimizer
@@ -32,21 +35,22 @@ def main() -> None:
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True, choices=list_archs())
     p.add_argument("--reduced", action="store_true",
-                   help="train the reduced (CPU-sized) config")
+                   help="train the reduced (CPU-sized) config in float32 "
+                        "(default: published widths, bf16 params)")
     p.add_argument("--steps", type=int, default=100)
     p.add_argument("--dp", type=int, default=0,
                    help="DP degree (0 = all devices)")
     p.add_argument("--global-batch", type=int, default=8)
     p.add_argument("--seq", type=int, default=64)
     p.add_argument("--lr", type=float, default=1e-3)
-    p.add_argument("--mode", default="ring",
-                   choices=["ring", "bidir", "psum", "compressed",
-                            "compressed-fused"])
+    p.add_argument("--mode", default="ring", choices=list(STEP_MODES))
     p.add_argument("--optimizer", default=None)
     p.add_argument("--checkpoint-dir", default=None)
     p.add_argument("--log-every", type=int, default=10)
     args = p.parse_args()
 
+    enable_compile_cache()
+    print(json.dumps({"device": device_summary()}), flush=True)
     cfg = get_arch(args.arch)
     if args.reduced:
         cfg = cfg.reduced()
@@ -56,7 +60,9 @@ def main() -> None:
     trainer = ElasticTrainer(model, opt, data,
                              global_batch=args.global_batch,
                              base_lr=args.lr, mode=args.mode,
-                             checkpoint_dir=args.checkpoint_dir)
+                             checkpoint_dir=args.checkpoint_dir,
+                             param_dtype=jnp.float32 if args.reduced
+                             else jnp.bfloat16)
     if args.checkpoint_dir:
         trainer.restore()
     dp = args.dp or len(jax.devices())

@@ -192,12 +192,15 @@ class RingWorkerGroup:
                 mode=self.mode,
                 n_buckets=self.n_buckets
                 if self.mode == "compressed-fused-overlap" else None)
+            # params and optimizer state are donated: the step writes the
+            # new state into their buffers instead of holding two copies
+            # (a full-width adamw state does not fit twice on one chip)
             smapped = jax.jit(jax.shard_map(
                 step_fn, mesh=mesh,
                 in_specs=(P(), P(), P("data")),
                 out_specs=(P(), P(), P()),
                 check_vma=False,
-            ))
+            ), donate_argnums=(0, 1))
             prog = _RingProgram(
                 mesh=mesh,
                 step_fn=smapped,
@@ -233,19 +236,31 @@ class RingWorkerGroup:
         return self.cache_key(self.workers) in self._warm
 
     def step(self, params, opt_state, batch):
-        """Run one compiled train step over the current ring."""
+        """Run one compiled train step over the current ring.
+
+        ``params`` and ``opt_state`` are donated: after the call they are
+        deleted, and the caller must continue from the returned state.
+        """
         out = self._current.step_fn(params, opt_state, batch)
         self._warm.add(self.cache_key(self.workers))
         return out
 
 
 class ElasticTrainer:
-    """Runs a job across slots with varying DP degree on host devices."""
+    """Runs a job across slots with varying DP degree on host devices.
+
+    ``params`` and ``opt_state`` always hold the live training state: every
+    step donates the previous arrays to the compiled step and rebinds both
+    attributes to its outputs, so a reference taken before a step is dead
+    after it. ``param_dtype`` is the dtype parameters are initialized and
+    trained in (optimizer moments stay float32 whatever it is).
+    """
 
     def __init__(self, model, optimizer: Optimizer, data, *,
                  global_batch: int, base_lr: float = 1e-3,
                  mode: str = "ring", checkpoint_dir: Optional[str] = None,
-                 n_buckets: Optional[int] = None):
+                 n_buckets: Optional[int] = None,
+                 param_dtype=jnp.float32):
         self.model = model
         self.optimizer = optimizer
         self.data = data
@@ -258,7 +273,7 @@ class ElasticTrainer:
                                      lr=base_lr,  # fixed global batch =>
                                      mode=mode,   # fixed LR (w splits only)
                                      n_buckets=n_buckets)
-        self.params = model.init(jax.random.PRNGKey(0), dtype=jnp.float32)
+        self.params = model.init(jax.random.PRNGKey(0), dtype=param_dtype)
         self.opt_state = optimizer.init(self.params)
         self.step = 0
         self.losses: List[float] = []

@@ -5,16 +5,3 @@ SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
 if SRC not in sys.path:
     sys.path.insert(0, SRC)
-
-import repro.compat  # noqa: E402
-
-repro.compat.install()
-
-# the container ships no hypothesis wheel; fall back to the bundled stub
-# (tests/_stubs) implementing the @given/strategies subset this suite uses
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                                    "_stubs"))
-    import hypothesis  # noqa: F401

@@ -342,9 +342,10 @@ def test_kernel_checker_rejects_non_dividing_rows():
 
 
 def test_kernel_checker_rejects_tile_budget_overflow():
-    # the gap the checker closes: _rows_per_tile resolves this to rows=1
-    # without complaint, but one sub-block row already overflows the budget
-    assert _rows_per_tile(4, 1 << 20, None, 5) == 1
+    # the gap the checker closes: _rows_per_tile resolves this to the whole
+    # 4-row dimension (the only tile height under 32 the TPU accepts)
+    # without complaint, but that tile already overflows the budget
+    assert _rows_per_tile(4, 1 << 20, None, 5) == 4
     result = akern.check_spec(akern.KernelSpec(4, 1 << 20))
     assert not result.ok
     assert any("_TILE_BUDGET_BYTES" in e for e in result.errors)

@@ -263,7 +263,7 @@ def test_collect_collectives_scan_and_guard_tracking():
         return out
 
     from jax.sharding import AbstractMesh, PartitionSpec as P
-    mesh = AbstractMesh((("ring", 2),))
+    mesh = AbstractMesh((2,), ("ring",))
     closed = jax.make_jaxpr(jax.shard_map(
         fn, mesh=mesh, in_specs=P("ring"), out_specs=P("ring"),
         check_vma=False))(jax.ShapeDtypeStruct((8,), jnp.float32))

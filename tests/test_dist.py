@@ -189,7 +189,7 @@ def _traced_psum_count(fn, grads):
     from jax.sharding import AbstractMesh, PartitionSpec as P
     from repro.analysis.collectives import collect_collectives
 
-    mesh = AbstractMesh((("d", 8),))
+    mesh = AbstractMesh((8,), ("d",))
     templates = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), grads)
     closed = jax.make_jaxpr(jax.shard_map(
@@ -336,7 +336,7 @@ def test_bucketed_ring_reduce_traced_bytes_match_wire_formula():
     sizes = [leaf.size for leaf in jax.tree.leaves(grads)]
     formula = wire_formula("int8-fused")
     w, n_buckets = 4, 3
-    mesh = AbstractMesh((("d", w),))
+    mesh = AbstractMesh((w,), ("d",))
     templates = jax.tree.map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), grads)
     closed = jax.make_jaxpr(jax.shard_map(

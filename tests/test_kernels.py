@@ -11,6 +11,7 @@ from hypothesis import strategies as st
 from repro.kernels import ops
 from repro.kernels.flash_attention import flash_attention_pallas
 from repro.kernels.quant_ring import (
+    bf16_add_cast_pallas,
     dequant_accumulate_pallas,
     dequant_add_quantize_pallas,
     quantize_pack_pallas,
@@ -266,14 +267,54 @@ def test_quant_ring_kernels_shape_sweep(nb, block, scale):
 
 
 def test_quant_ring_rows_per_tile_validation():
-    x = jnp.ones((6, 128), jnp.float32)
+    x = rand(jax.random.PRNGKey(5), (96, 128))
     with pytest.raises(ValueError, match="must divide"):
-        quantize_pack_pallas(x, interpret=True, rows_per_tile=4)
+        quantize_pack_pallas(x, interpret=True, rows_per_tile=40)
+    # a tile the TPU compiler would refuse: neither the whole dimension
+    # nor a multiple of the native int8 tile height
+    with pytest.raises(ValueError, match="multiple of 32"):
+        quantize_pack_pallas(x, interpret=True, rows_per_tile=48)
     # a valid explicit tiling matches the default
-    q1, s1 = quantize_pack_pallas(x, interpret=True, rows_per_tile=2)
+    q1, s1 = quantize_pack_pallas(x, interpret=True, rows_per_tile=32)
     q2, s2 = quantize_pack_pallas(x, interpret=True)
     np.testing.assert_array_equal(np.asarray(q1), np.asarray(q2))
     np.testing.assert_array_equal(np.asarray(s1), np.asarray(s2))
+
+
+@pytest.mark.parametrize("kernel", ["quantize_pack", "dequant_add_quantize",
+                                    "dequant_accumulate", "dequant",
+                                    "bf16_add_cast"])
+def test_quant_ring_ragged_last_tile_bit_identical(kernel, monkeypatch):
+    """A row count with no aligned divisor under the VMEM budget runs a
+    cdiv grid with a ragged last tile; every row must come out exactly as
+    the single-tile call computes it."""
+    from repro.kernels import quant_ring
+
+    nb, block = 100, 128
+    x = rand(jax.random.PRNGKey(11), (nb, block), scale=3.0)
+    acc = rand(jax.random.PRNGKey(12), (nb, block))
+    q, s = quantize_pack_pallas(x, interpret=True)
+
+    def run():
+        if kernel == "quantize_pack":
+            return quantize_pack_pallas(x, interpret=True)
+        if kernel == "dequant_add_quantize":
+            return dequant_add_quantize_pallas(q, s, acc, interpret=True)
+        if kernel == "dequant_accumulate":
+            return (dequant_accumulate_pallas(q, s, acc, interpret=True),)
+        if kernel == "dequant":
+            return (dequant_accumulate_pallas(q, s, None, interpret=True),)
+        return (bf16_add_cast_pallas(x.astype(jnp.bfloat16), acc,
+                                     interpret=True),)
+
+    whole = run()
+    # budget for exactly 32 rows of the widest (10 B/elem) working set:
+    # 100 rows then tile as 32 + 32 + 32 + a ragged 4
+    monkeypatch.setattr(quant_ring, "_TILE_BUDGET_BYTES", 32 * block * 10)
+    assert quant_ring._rows_per_tile(nb, block, None, 10) == 32
+    tiled = run()
+    for a, b in zip(whole, tiled):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_quant_ring_ops_wrappers_run():

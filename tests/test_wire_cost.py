@@ -126,7 +126,7 @@ def _ppermute_stats(jaxpr):
 
 
 def _traced_ring_stats(d: int, w: int, fused: bool):
-    mesh = AbstractMesh((("d", w),))
+    mesh = AbstractMesh((w,), ("d",))
     fn = jax.shard_map(
         partial(compressed_ring_all_reduce, axis_name="d", fused=fused,
                 interpret=True),
