@@ -36,6 +36,7 @@ import scipy.sparse as sp
 from repro.cluster.topology import Edge, Embedding, ResourceState, SubstrateGraph
 from repro.core.lp import LPResult, pdhg_solve, solve_ilp, solve_lp
 from repro.core.problem import Job, ScheduleState
+from repro.launch.runtime import span
 
 
 @dataclasses.dataclass
@@ -667,46 +668,50 @@ def solve_slot(
         jobs = [jobs[k] for k in sorted(ranked[: cfg.admission_window])]
     job_map = {j.id: j for j in jobs}
 
-    # steps 1-2: bounds + candidates for every kappa in {1..q_i}. The
-    # vectorized path computes one packability matrix for the whole slot and
-    # shares each job's row across its kappas — bit-identical values to the
-    # per-call worker_upper_bound/generate_candidates rebuild (the caps are
-    # integers and res is not mutated until step 7's scratch clone).
-    caps_rows: List[Optional[Dict[int, int]]]
-    if cfg.vectorized and jobs:
-        server_ids, caps_mat = slot_caps_matrix(res, jobs)
-        caps_rows = [
-            {sid: int(caps_mat[k, i]) for i, sid in enumerate(server_ids)}
-            for k in range(len(jobs))
-        ]
-    else:
-        caps_rows = [None] * len(jobs)
-    cands: List[Candidate] = []
-    for job, caps in zip(jobs, caps_rows):
-        if caps is None:
-            q = worker_upper_bound(res, job, state.remaining(job))
+    with span("gadget.candidates", jobs=len(jobs)):
+        # steps 1-2: bounds + candidates for every kappa in {1..q_i}. The
+        # vectorized path computes one packability matrix for the whole
+        # slot and shares each job's row across its kappas — bit-identical
+        # values to the per-call worker_upper_bound/generate_candidates
+        # rebuild (the caps are integers and res is not mutated until step
+        # 7's scratch clone).
+        caps_rows: List[Optional[Dict[int, int]]]
+        if cfg.vectorized and jobs:
+            server_ids, caps_mat = slot_caps_matrix(res, jobs)
+            caps_rows = [
+                {sid: int(caps_mat[k, i])
+                 for i, sid in enumerate(server_ids)}
+                for k in range(len(jobs))
+            ]
         else:
-            packable = int(sum(caps.values()))
-            q = int(max(0, math.floor(
-                min(job.max_workers, state.remaining(job), packable) + 1e-9
-            )))
-        for kappa in range(1, q + 1):
-            pi = state.marginal_utility(job, kappa)
-            if pi <= 0:
-                continue
-            cands.extend(
-                generate_candidates(res, job, kappa, pi, cfg, rng, caps=caps)
-            )
+            caps_rows = [None] * len(jobs)
+        cands: List[Candidate] = []
+        for job, caps in zip(jobs, caps_rows):
+            if caps is None:
+                q = worker_upper_bound(res, job, state.remaining(job))
+            else:
+                packable = int(sum(caps.values()))
+                q = int(max(0, math.floor(
+                    min(job.max_workers, state.remaining(job), packable)
+                    + 1e-9
+                )))
+            for kappa in range(1, q + 1):
+                pi = state.marginal_utility(job, kappa)
+                if pi <= 0:
+                    continue
+                cands.extend(generate_candidates(res, job, kappa, pi, cfg,
+                                                 rng, caps=caps))
     if not cands:
         return GvneResult([], 0.0, 0.0, 0.0, 0, True, {"n_candidates": 0})
 
-    # step 3: LP relaxation + ring selection (Lemma 7)
-    phi, lp_value = _solve_selection_lp(cands, res, cfg.lp_engine)
-    ring_sizes = lp_ring_selection(cands, phi)
+    with span("gadget.lp", jobs=len(jobs), candidates=len(cands)):
+        # step 3: LP relaxation + ring selection (Lemma 7)
+        phi, lp_value = _solve_selection_lp(cands, res, cfg.lp_engine)
+        ring_sizes = lp_ring_selection(cands, phi)
 
-    # step 4: augmented LP restricted to selected ring sizes
-    aug = [c for c in cands if ring_sizes.get(c.job_id) == c.kappa]
-    phi_aug, _ = _solve_selection_lp(aug, res, cfg.lp_engine)
+        # step 4: augmented LP restricted to selected ring sizes
+        aug = [c for c in cands if ring_sizes.get(c.job_id) == c.kappa]
+        phi_aug, _ = _solve_selection_lp(aug, res, cfg.lp_engine)
 
     # step 5: mapping-selection tuples M_i
     by_job: Dict[int, List[Tuple[float, Candidate]]] = {}
@@ -714,40 +719,47 @@ def solve_slot(
         if f > 1e-9:
             by_job.setdefault(c.job_id, []).append((float(f), c))
 
-    # step 6: randomized rounding until (alpha, beta^r, gamma)-approx or u_b
-    betas, gamma_slack = _violation_slacks(aug, res, cfg.epsilon)
-    best_choice: List[Candidate] = []
-    best_value = -1.0
-    accepted = False
-    n_rounds = 0
-    for n_rounds in range(1, cfg.u_b + 1):
-        chosen = _round_once(by_job, rng)
-        value, node_use, edge_use = _eval_choice(chosen, res)
-        if value > best_value:
-            best_value, best_choice = value, chosen
-        ok = value >= cfg.alpha * lp_value - 1e-9
-        for (s, r), v in node_use.items():
-            if v > betas.get(r, 1.0) * res.free_node[s].get(r, 0.0) + 1e-9:
-                ok = False
-                break
-        if ok:
-            for e, v in edge_use.items():
-                if v > gamma_slack * res.admissible_edge_capacity(e) + 1e-9:
+    with span("gadget.round", jobs=len(jobs), candidates=len(aug)):
+        # step 6: randomized rounding until (alpha, beta^r, gamma)-approx
+        # or u_b
+        betas, gamma_slack = _violation_slacks(aug, res, cfg.epsilon)
+        best_choice: List[Candidate] = []
+        best_value = -1.0
+        accepted = False
+        n_rounds = 0
+        for n_rounds in range(1, cfg.u_b + 1):
+            chosen = _round_once(by_job, rng)
+            value, node_use, edge_use = _eval_choice(chosen, res)
+            if value > best_value:
+                best_value, best_choice = value, chosen
+            ok = value >= cfg.alpha * lp_value - 1e-9
+            for (s, r), v in node_use.items():
+                cap = betas.get(r, 1.0) * res.free_node[s].get(r, 0.0)
+                if v > cap + 1e-9:
                     ok = False
                     break
-        if ok:
-            accepted = True
-            best_value, best_choice = value, chosen
-            break
+            if ok:
+                for e, v in edge_use.items():
+                    cap = gamma_slack * res.admissible_edge_capacity(e)
+                    if v > cap + 1e-9:
+                        ok = False
+                        break
+            if ok:
+                accepted = True
+                best_value, best_choice = value, chosen
+                break
 
-    # step 7: strict-feasibility repair + greedy backfill of rejected jobs
-    scratch = res.clone()
-    kept = _repair(best_choice, scratch, job_map)
-    kept = _backfill(kept, cands, scratch, job_map, state)
-    if res.oversubscription > 1.0:
-        # the LP cannot price fair-sharing; re-route rings that landed on
-        # oversubscribed edges now that the slot's full commit set is known
-        kept = _reroute_contended(kept, scratch, job_map)
+    with span("gadget.repair", jobs=len(jobs), candidates=len(cands)):
+        # step 7: strict-feasibility repair + greedy backfill of rejected
+        # jobs
+        scratch = res.clone()
+        kept = _repair(best_choice, scratch, job_map)
+        kept = _backfill(kept, cands, scratch, job_map, state)
+        if res.oversubscription > 1.0:
+            # the LP cannot price fair-sharing; re-route rings that landed
+            # on oversubscribed edges now that the slot's full commit set
+            # is known
+            kept = _reroute_contended(kept, scratch, job_map)
     embeddings = [c.embedding for c in kept]
     final_value = sum(
         state.marginal_utility(job_map[e.job_id], e.n_workers) for e in embeddings

@@ -1,4 +1,5 @@
-"""Process start-up shared by the entry points: compile cache, device line.
+"""Process start-up shared by the entry points: compile cache, device line,
+and the host spans the program marks its work with.
 
 Every entry point (``launch/train.py``, ``launch/serve.py``, the
 ``examples/`` drivers and ``chip_smoke.py``) calls
@@ -10,6 +11,8 @@ from __future__ import annotations
 
 import os
 from typing import Dict
+
+from jax.profiler import TraceAnnotation
 
 # <repo>/.jax_cache: a fixed path, because the cache key includes it — a
 # directory that moved between runs would never hit
@@ -40,3 +43,14 @@ def device_summary() -> Dict[str, object]:
     devices = jax.devices()
     return {"platform": devices[0].platform,
             "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def span(name: str, **ids) -> TraceAnnotation:
+    """A host span ``repro.<name>`` in the profiler's trace, on the clock of
+    the device planes; ``ids`` (integers) become the event's stats.
+
+    Spans mark host code only, never the inside of a jitted function, and
+    nest as the calls that open them do. With no profiler running a span
+    is inert and costs about a microsecond.
+    """
+    return TraceAnnotation("repro." + name, **ids)

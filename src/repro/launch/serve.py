@@ -47,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_arch, list_archs
-from repro.launch.runtime import device_summary, enable_compile_cache
+from repro.launch.runtime import device_summary, enable_compile_cache, span
 from repro.models.model import (
     build_model,
     cache_lane,
@@ -167,8 +167,10 @@ class Request:
     back until the clock reaches it, which is how bursty arrival traces are
     replayed at the engine level. The ``*_clock`` stamps are filled by the
     engine (TTFT = ``first_token_clock - arrival``, in clock ticks); the
-    ``*_time`` stamps are wall seconds for throughput reporting only —
-    nothing decision-making reads them.
+    ``*_time`` stamps are ``time.monotonic()`` seconds for reporting only —
+    nothing decision-making reads them. ``admit_time - submit_time`` is the
+    request's wait in the queue, ``first_token_time - admit_time`` its own
+    prefill.
     """
 
     id: int
@@ -182,6 +184,7 @@ class Request:
     first_token_clock: Optional[int] = None
     done_clock: Optional[int] = None
     submit_time: Optional[float] = None
+    admit_time: Optional[float] = None
     first_token_time: Optional[float] = None
     done_time: Optional[float] = None
 
@@ -211,6 +214,13 @@ class ServingEngine:
     ``STATIC_CLOSURE_ATTRS`` + :meth:`closure_fingerprint` mirror the
     ``RingWorkerGroup`` recompile-hazard machinery — audited at runtime by
     :func:`audit_serving_engine`.
+
+    Work counters: ``prefill_chunks`` (compiled prefill calls),
+    ``prefill_padded_tokens`` (the zero tail of each prompt's final chunk)
+    and ``decode_lane_steps`` (live lanes summed over decode steps). Useful
+    prefill work is ``1 - prefill_padded_tokens / (prefill_chunks *
+    prefill_chunk)``; useful decode work is ``decode_lane_steps /
+    (decode_steps * max_batch)``.
     """
 
     # attrs closed over by the compiled steps: mutating any of them after
@@ -240,6 +250,9 @@ class ServingEngine:
         self.finished: List[Request] = []
         self.clock = 0          # compiled decode/prefill calls so far
         self.decode_steps = 0
+        self.decode_lane_steps = 0
+        self.prefill_chunks = 0
+        self.prefill_padded_tokens = 0
         self.compile_count = 0          # decode-step traces (pinned == 1)
         self.prefill_compile_count = 0
         self.aux_compile_count = 0      # zero-lane traces
@@ -310,17 +323,28 @@ class ServingEngine:
         a backend spending a slot's token budget); default: fill all lanes.
         """
         admitted: List[Request] = []
-        while self.queue and not self.active.all():
-            if limit is not None and len(admitted) >= limit:
-                break
-            lane = int(np.argmin(self.active))
-            req = self.queue.popleft()
+        with span("serve.admit"):
+            while self.queue and not self.active.all():
+                if limit is not None and len(admitted) >= limit:
+                    break
+                req = self.queue.popleft()
+                self._prefill_onto_lane(req, int(np.argmin(self.active)))
+                admitted.append(req)
+        return admitted
+
+    def _prefill_onto_lane(self, req: Request, lane: int) -> None:
+        """Prefill ``req`` onto the free ``lane``: its first token, then
+        the lane joins the decode batch unless the request is done."""
+        req.admit_time = time.monotonic()
+        prompt = np.asarray(req.prompt, np.int32)
+        c = self.prefill_chunk
+        chunks = -(-len(prompt) // c)
+        with span("serve.prefill", req=req.id, tokens=len(prompt),
+                  chunks=chunks):
             # evict barrier: the lane may hold a retired request's
             # recurrent state — zero it before the new prompt conditions
             # on it (attention caches are self-masking, SSM/WKV state is not)
             self.cache = self._zero(self.cache, jnp.int32(lane))
-            prompt = np.asarray(req.prompt, np.int32)
-            c = self.prefill_chunk
             n_total = jnp.int32(len(prompt))
             last = None
             for c0 in range(0, len(prompt), c):
@@ -332,43 +356,49 @@ class ServingEngine:
                     jnp.asarray(chunk[None, :]), jnp.int32(c0), n_total)
                 self.clock += 1
             tok = int(np.argmax(np.asarray(last)))
-            req.tokens.append(tok)
-            req.first_token_clock = self.clock
-            req.first_token_time = time.monotonic()
-            if self._is_done(req, tok, len(prompt)):
-                self._retire(req)
-            else:
-                self.lane_req[lane] = req
-                self.positions[lane] = len(prompt)
-                self.last_token[lane] = tok
-                self.active[lane] = True
-            admitted.append(req)
-        return admitted
+        self.prefill_chunks += chunks
+        self.prefill_padded_tokens += chunks * c - len(prompt)
+        req.tokens.append(tok)
+        req.first_token_clock = self.clock
+        req.first_token_time = time.monotonic()
+        if self._is_done(req, tok, len(prompt)):
+            self._retire(req)
+        else:
+            self.lane_req[lane] = req
+            self.positions[lane] = len(prompt)
+            self.last_token[lane] = tok
+            self.active[lane] = True
 
     def step(self) -> List[Request]:
         """One fixed-shape decode step over every lane; returns the requests
         that finished (EOS / max_new / cache-full) this step."""
-        if not self.active.any():
+        lanes = np.nonzero(self.active)[0]
+        if not len(lanes):
             return []
-        nxt, self.cache = self._decode(
-            self.params, self.cache,
-            jnp.asarray(self.last_token.reshape(-1, 1)),
-            jnp.asarray(self.positions), jnp.asarray(self.active))
-        nxt = np.asarray(nxt)
-        self.clock += 1
-        self.decode_steps += 1
-        done: List[Request] = []
-        for lane in np.nonzero(self.active)[0]:
-            req = self.lane_req[lane]
-            tok = int(nxt[lane])
-            req.tokens.append(tok)
-            self.positions[lane] += 1
-            self.last_token[lane] = tok
-            if self._is_done(req, tok, int(self.positions[lane])):
-                self.active[lane] = False
-                self.lane_req[lane] = None
-                self._retire(req)
-                done.append(req)
+        with span("serve.step", lanes=len(lanes)):
+            with span("serve.step.dispatch"):
+                nxt, self.cache = self._decode(
+                    self.params, self.cache,
+                    jnp.asarray(self.last_token.reshape(-1, 1)),
+                    jnp.asarray(self.positions), jnp.asarray(self.active))
+            with span("serve.step.readback"):
+                nxt = np.asarray(nxt)
+            self.clock += 1
+            self.decode_steps += 1
+            self.decode_lane_steps += len(lanes)
+            done: List[Request] = []
+            with span("serve.step.lanes"):
+                for lane in lanes:
+                    req = self.lane_req[lane]
+                    tok = int(nxt[lane])
+                    req.tokens.append(tok)
+                    self.positions[lane] += 1
+                    self.last_token[lane] = tok
+                    if self._is_done(req, tok, int(self.positions[lane])):
+                        self.active[lane] = False
+                        self.lane_req[lane] = None
+                        self._retire(req)
+                        done.append(req)
         return done
 
     def _is_done(self, req: Request, tok: int, position: int) -> bool:

@@ -48,6 +48,7 @@ from typing import (
 
 from repro.sched.api import SchedulerContext, SlotDecision
 from repro.cluster.calibrate import RingTimingSample, calibrate_profile
+from repro.launch.runtime import span
 
 if TYPE_CHECKING:  # annotation-only (keeps jax out of the import path)
     from repro.cluster.topology import Embedding
@@ -388,6 +389,11 @@ class LiveBackend:
     # -- the backend contract ----------------------------------------------
     def execute_slot(self, decision: SlotDecision,
                      execution: SlotExecution) -> SlotOutcome:
+        with span("backend.execute", t=execution.t):
+            return self._execute(decision, execution)
+
+    def _execute(self, decision: SlotDecision,
+                 execution: SlotExecution) -> SlotOutcome:
         from repro.training.elastic import SlotPlan
 
         factors: List[float] = []
@@ -433,8 +439,10 @@ class LiveBackend:
                 # costs credited worker-time
                 leave = (min(int(steps * self.leave_fraction), steps - 1),
                          n_leave)
-            out = trainer.run_slot(
-                SlotPlan(workers=emb.n_workers, steps=steps, leave=leave))
+            with span("train.slot", job=emb.job_id, workers=emb.n_workers,
+                      steps=steps):
+                out = trainer.run_slot(
+                    SlotPlan(workers=emb.n_workers, steps=steps, leave=leave))
             if self.audit_cache:
                 group = getattr(trainer, "group", None)
                 if group is not None:
@@ -448,8 +456,9 @@ class LiveBackend:
             nominal = self.steps_per_slot * max(emb.n_workers, 1)
             factor = min(1.0, out.get("worker_steps", 0) / nominal)
             factors.append(factor)
-            self._record_timings(emb.job_id, trainer,
-                                 out.get("timings", {}), execution)
+            with span("backend.calibrate", job=emb.job_id):
+                self._record_timings(emb.job_id, trainer,
+                                     out.get("timings", {}), execution)
             row = {"t": execution.t, "job_id": emb.job_id,
                    "scheduled_workers": emb.n_workers, "factor": factor,
                    **{k: out[k] for k in

@@ -45,6 +45,7 @@ import numpy as np
 from repro.analysis.sanitize import SlotSanitizer, sanitize_enabled
 from repro.cluster.topology import Embedding, ResourceState
 from repro.core.problem import DDLJSInstance, ScheduleState
+from repro.launch.runtime import span
 from repro.sched.api import (
     ContentionConfig,
     Scheduler,
@@ -162,159 +163,173 @@ class OnlineDriver:
         pending = set(job_order)
 
         for t in range(inst.horizon):
-            # -- pre-slot events: arrivals + repairs + straggler transitions
-            pre: List[ClusterEvent] = [SlotTick(t)]
-            pre += [JobArrival(t, jid) for jid in arrivals_at.get(t, ())]
-            pre += stream.pre_slot(t)
-            for ev in pre:
-                if isinstance(ev, ServerRecovery):
-                    failed.discard(ev.server_id)
-                elif isinstance(ev, ServerFailure):
-                    failed.add(ev.server_id)  # pre-slot failure: down before
-                    straggling.pop(ev.server_id, None)  # scheduling
-                elif isinstance(ev, StragglerOnset):
-                    straggling[ev.server_id] = ev.factor
-                elif isinstance(ev, StragglerEnd):
-                    straggling.pop(ev.server_id, None)
-                elif isinstance(ev, RequestArrival):
-                    # no driver state: the scheduler prices the backlog via
-                    # on_event below, and the serving backend consumes the
-                    # arrival from SlotExecution.pre_events
-                    pass
+            with span("slot", t=t):
+                # -- pre-slot events: arrivals, repairs, straggler changes
+                pre: List[ClusterEvent] = [SlotTick(t)]
+                pre += [JobArrival(t, jid) for jid in arrivals_at.get(t, ())]
+                pre += stream.pre_slot(t)
+                for ev in pre:
+                    if isinstance(ev, ServerRecovery):
+                        failed.discard(ev.server_id)
+                    elif isinstance(ev, ServerFailure):
+                        # pre-slot failure: down before scheduling
+                        failed.add(ev.server_id)
+                        straggling.pop(ev.server_id, None)
+                    elif isinstance(ev, StragglerOnset):
+                        straggling[ev.server_id] = ev.factor
+                    elif isinstance(ev, StragglerEnd):
+                        straggling.pop(ev.server_id, None)
+                    elif isinstance(ev, RequestArrival):
+                        # no driver state: the scheduler prices the backlog
+                        # via on_event below, and the serving backend
+                        # consumes the arrival from SlotExecution.pre_events
+                        pass
 
-            res = ResourceState(
-                inst.graph, oversubscription=self.contention.oversubscription
-            )
-            down_now = frozenset(failed)
-            for sid in sorted(down_now):  # zero capacity of failed servers
-                for r in res.free_node[sid]:
-                    res.free_node[sid][r] = 0.0
-
-            ctx = SchedulerContext(
-                t=t,
-                res=res,
-                state=state,
-                contention=self.contention,
-                failed=down_now,
-                straggling=dict(straggling),
-            )
-            for ev in pre:
-                log.append(ev)
-                sched.on_event(ev, ctx)
-
-            # -- the decision (Algorithm 1 line 4); scheduler commits into res
-            decision = sched.schedule_slot(ctx)
-
-            # -- mid-slot events: the failure wave + scripted ring changes
-            mid = stream.mid_slot(t)
-            wave: set = set()
-            left: Dict[int, int] = {}
-            for ev in mid:
-                if isinstance(ev, ServerFailure):
-                    wave.add(ev.server_id)
-                    failed.add(ev.server_id)
-                    # a downed server stops straggling (the pre-slot branch
-                    # already did this); without the pop a recovered server
-                    # kept being priced at straggler speed
-                    straggling.pop(ev.server_id, None)
-                elif isinstance(ev, ServerRecovery):
-                    failed.discard(ev.server_id)
-                elif isinstance(ev, StragglerOnset):  # affects later slots
-                    straggling[ev.server_id] = ev.factor
-                elif isinstance(ev, StragglerEnd):
-                    straggling.pop(ev.server_id, None)
-                elif isinstance(ev, WorkerLeave):
-                    left[ev.job_id] = left.get(ev.job_id, 0) + ev.n
-                elif isinstance(ev, WorkerJoin):
-                    # explicitly ignored mid-slot: joins reshape rings at
-                    # the next slot boundary (events.py contract) — the
-                    # decision for this slot has already been placed
-                    pass
-                log.append(ev)
-                sched.on_event(ev, ctx)
-
-            # -- execution (analytic pricing or real training) + accounting
-            committed: List[Embedding] = list(decision.embeddings)
-            for e in committed:
-                assert e.job_id in res.committed, \
-                    "scheduler must commit embeddings"
-            outcome = self.backend.execute_slot(
-                decision,
-                SlotExecution(ctx=ctx, wave=frozenset(wave), left=left,
-                              pre_events=tuple(pre)),
-            )
-            if len(outcome.factors) != len(committed):
-                raise ValueError(
-                    f"{getattr(self.backend, 'name', self.backend)!r} "
-                    f"backend returned {len(outcome.factors)} factors for "
-                    f"{len(committed)} embeddings"
+                res = ResourceState(
+                    inst.graph,
+                    oversubscription=self.contention.oversubscription,
                 )
-            placed = 0
-            effective = 0.0
-            for e, factor in zip(committed, outcome.factors):
-                placed += e.n_workers
-                effective += factor * e.n_workers
-                log.append(EmbeddingCommitted(t, e.job_id, e.n_workers))
-            # z + history accounting via the single shared path
-            state.commit_slot(committed, outcome.factors)
+                down_now = frozenset(failed)
+                for sid in sorted(down_now):  # failed servers: no capacity
+                    for r in res.free_node[sid]:
+                        res.free_node[sid][r] = 0.0
 
-            # execution-generated events (the serving backend's request
-            # lifecycle) join the log before the sanitizer runs, so its
-            # serving-accounting check re-derives SLO attainment from
-            # exactly the log a replay of this run would see
-            for ev in outcome.events:
-                if isinstance(ev, (RequestFirstToken, RequestCompletion)):
-                    # explicitly log-only: TTFT/TPOT/attainment are derived
-                    # from the event log, never from driver state
-                    pass
-                log.append(ev)
-                sched.on_event(ev, ctx)
-
-            if sanitizer is not None:  # read-only invariant re-derivation
-                sanitizer.check_slot(ctx=ctx, committed=committed,
-                                     outcome=outcome, events=log)
-
-            # completion check over the candidate set only: the initial sweep
-            # (t=0) covers jobs whose budget starts exhausted; afterwards only
-            # jobs whose z changed this slot can cross the threshold. Checked
-            # in inst.jobs order, so the event log is identical to a full
-            # per-slot sweep.
-            if t == 0:
-                candidates = list(pending)
-            else:
-                candidates = {e.job_id for e in committed} & pending
-            for jid in sorted(candidates, key=job_order.__getitem__):
-                if state.remaining(jobs_by_id[jid]) <= 1e-9:
-                    pending.discard(jid)
-                    completion[jid] = t
-                    ev = JobCompletion(t, jid)
+                ctx = SchedulerContext(
+                    t=t,
+                    res=res,
+                    state=state,
+                    contention=self.contention,
+                    failed=down_now,
+                    straggling=dict(straggling),
+                )
+                for ev in pre:
                     log.append(ev)
                     sched.on_event(ev, ctx)
 
-            records.append(
-                SlotRecord(
-                    t=t,
-                    n_active=decision.n_active,
-                    n_embedded=len(committed),
-                    workers_placed=placed,
-                    effective_worker_time=effective,
-                    utility_total=state.total_utility(),
-                    # utilization over healthy capacity only: servers that
-                    # were down when the slot was scheduled don't count as
-                    # "in use"
-                    gpu_utilization=res.utilization(exclude=down_now).get(
-                        "gpus", 0.0
-                    ),
-                    failed_servers=len(failed),
-                    max_edge_contention=res.max_edge_contention(),
-                    mean_contention_factor=(
-                        float(np.mean(outcome.contention_factors))
-                        if outcome.contention_factors
-                        else 1.0
-                    ),
-                    lost_embeddings=outcome.lost,
+                # -- the decision (Algorithm 1 line 4); the scheduler
+                # commits into res
+                with span("sched.decide", t=t):
+                    decision = sched.schedule_slot(ctx)
+
+                # -- mid-slot events: the failure wave + scripted ring changes
+                mid = stream.mid_slot(t)
+                wave: set = set()
+                left: Dict[int, int] = {}
+                for ev in mid:
+                    if isinstance(ev, ServerFailure):
+                        wave.add(ev.server_id)
+                        failed.add(ev.server_id)
+                        # a downed server stops straggling (the pre-slot
+                        # branch already did this); without the pop a
+                        # recovered server kept being priced at straggler
+                        # speed
+                        straggling.pop(ev.server_id, None)
+                    elif isinstance(ev, ServerRecovery):
+                        failed.discard(ev.server_id)
+                    elif isinstance(ev, StragglerOnset):  # later slots
+                        straggling[ev.server_id] = ev.factor
+                    elif isinstance(ev, StragglerEnd):
+                        straggling.pop(ev.server_id, None)
+                    elif isinstance(ev, WorkerLeave):
+                        left[ev.job_id] = left.get(ev.job_id, 0) + ev.n
+                    elif isinstance(ev, WorkerJoin):
+                        # explicitly ignored mid-slot: joins reshape rings at
+                        # the next slot boundary (events.py contract) — the
+                        # decision for this slot has already been placed
+                        pass
+                    log.append(ev)
+                    sched.on_event(ev, ctx)
+
+                # -- execution (analytic pricing or real training), then
+                # accounting
+                committed: List[Embedding] = list(decision.embeddings)
+                for e in committed:
+                    assert e.job_id in res.committed, \
+                        "scheduler must commit embeddings"
+                outcome = self.backend.execute_slot(
+                    decision,
+                    SlotExecution(ctx=ctx, wave=frozenset(wave), left=left,
+                                  pre_events=tuple(pre)),
                 )
-            )
+                with span("slot.commit", t=t):
+                    if len(outcome.factors) != len(committed):
+                        name = getattr(self.backend, "name", self.backend)
+                        raise ValueError(
+                            f"{name!r} backend returned "
+                            f"{len(outcome.factors)} factors for "
+                            f"{len(committed)} embeddings"
+                        )
+                    placed = 0
+                    effective = 0.0
+                    for e, factor in zip(committed, outcome.factors):
+                        placed += e.n_workers
+                        effective += factor * e.n_workers
+                        log.append(
+                            EmbeddingCommitted(t, e.job_id, e.n_workers))
+                    # z + history accounting via the single shared path
+                    state.commit_slot(committed, outcome.factors)
+
+                    # execution-generated events (the serving backend's
+                    # request lifecycle) join the log before the sanitizer
+                    # runs, so its serving-accounting check re-derives SLO
+                    # attainment from exactly the log a replay of this run
+                    # would see
+                    for ev in outcome.events:
+                        if isinstance(ev, (RequestFirstToken,
+                                           RequestCompletion)):
+                            # explicitly log-only: TTFT/TPOT/attainment are
+                            # derived from the event log, never from driver
+                            # state
+                            pass
+                        log.append(ev)
+                        sched.on_event(ev, ctx)
+
+                    # read-only invariant re-derivation
+                    if sanitizer is not None:
+                        sanitizer.check_slot(ctx=ctx, committed=committed,
+                                             outcome=outcome, events=log)
+
+                    # completion check over the candidate set only: the
+                    # initial sweep (t=0) covers jobs whose budget starts
+                    # exhausted; afterwards only jobs whose z changed this
+                    # slot can cross the threshold. Checked in inst.jobs
+                    # order, so the event log is identical to a full
+                    # per-slot sweep.
+                    if t == 0:
+                        candidates = list(pending)
+                    else:
+                        candidates = {e.job_id for e in committed} & pending
+                    for jid in sorted(candidates, key=job_order.__getitem__):
+                        if state.remaining(jobs_by_id[jid]) <= 1e-9:
+                            pending.discard(jid)
+                            completion[jid] = t
+                            ev = JobCompletion(t, jid)
+                            log.append(ev)
+                            sched.on_event(ev, ctx)
+
+                    records.append(
+                        SlotRecord(
+                            t=t,
+                            n_active=decision.n_active,
+                            n_embedded=len(committed),
+                            workers_placed=placed,
+                            effective_worker_time=effective,
+                            utility_total=state.total_utility(),
+                            # utilization over healthy capacity only:
+                            # servers that were down when the slot was
+                            # scheduled don't count as "in use"
+                            gpu_utilization=res.utilization(
+                                exclude=down_now).get("gpus", 0.0),
+                            failed_servers=len(failed),
+                            max_edge_contention=res.max_edge_contention(),
+                            mean_contention_factor=(
+                                float(np.mean(outcome.contention_factors))
+                                if outcome.contention_factors
+                                else 1.0
+                            ),
+                            lost_embeddings=outcome.lost,
+                        )
+                    )
         return SimResult(
             scheduler=sched.name,
             records=records,

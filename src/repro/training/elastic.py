@@ -43,6 +43,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.dist.registry import STEP_MODES
+from repro.launch.runtime import span
 from repro.training.checkpoint import load_checkpoint, save_checkpoint
 from repro.training.optimizer import Optimizer
 from repro.training.train_step import make_ring_train_step
@@ -294,12 +295,11 @@ class ElasticTrainer:
         -> best wall seconds/step), ``re_rings`` (mid-slot re-rings).
         """
         if plan.workers <= 0:
-            if self.checkpoint_dir:
-                save_checkpoint(self.checkpoint_dir, params=self.params,
-                                opt_state=self.opt_state, step=self.step)
+            self._checkpoint(0)
             return {"steps": 0, "loss": float("nan")}
-        w = self.group.form(plan.workers)
-        self._reshard_state()
+        with span("train.form", workers=plan.workers):
+            w = self.group.form(plan.workers)
+            self._reshard_state()
         self.resharding_events += 1
 
         segments: List[Tuple[int, int]] = [(w, plan.steps)]
@@ -315,41 +315,57 @@ class ElasticTrainer:
         timings: Dict[int, float] = {}
         for idx, (seg_w, seg_steps) in enumerate(segments):
             if idx > 0:
-                seg_w = self.group.re_ring(seg_w)
-                self._reshard_state()
+                with span("train.re_ring", workers=seg_w):
+                    seg_w = self.group.re_ring(seg_w)
+                    self._reshard_state()
                 self.re_ring_events += 1
                 re_rings += 1
             for _ in range(seg_steps):
-                batch = self.data.batch(self.step)  # step-indexed: elastic-safe
-                batch = self.group.shard_batch(batch)
-                was_warm = self.group.warm
-                t0 = time.perf_counter()
-                self.params, self.opt_state, metrics = self.group.step(
-                    self.params, self.opt_state, batch)
-                loss = float(metrics["loss"])  # sync: timing covers the step
-                dt = time.perf_counter() - t0
+                with span("train.step", step=self.step, workers=seg_w):
+                    loss, dt, was_warm = self._step()
                 if was_warm:  # a cold step times the trace/compile, not the
                     # ring — never report it (it would poison calibration)
                     timings[seg_w] = min(timings.get(seg_w, float("inf")), dt)
                 self.losses.append(loss)
                 self.step += 1
                 worker_steps += seg_w
-        if self.checkpoint_dir:
-            save_checkpoint(self.checkpoint_dir, params=self.params,
-                            opt_state=self.opt_state, step=self.step)
+        self._checkpoint(w)
         return {"steps": plan.steps, "loss": loss, "workers": w,
                 "worker_steps": worker_steps, "timings": timings,
                 "re_rings": re_rings}
 
+    def _step(self) -> Tuple[float, float, bool]:
+        """One train step on the current ring: its loss, the seconds from
+        dispatch to the loss on the host, and whether the step was compiled
+        before it ran."""
+        with span("train.input"):
+            # step-indexed: elastic-safe
+            batch = self.group.shard_batch(self.data.batch(self.step))
+        was_warm = self.group.warm
+        t0 = time.perf_counter()
+        with span("train.dispatch"):
+            self.params, self.opt_state, metrics = self.group.step(
+                self.params, self.opt_state, batch)
+        with span("train.sync"):
+            loss = float(metrics["loss"])  # sync: timing covers the step
+        return loss, time.perf_counter() - t0, was_warm
+
+    def _checkpoint(self, workers: int) -> None:
+        if self.checkpoint_dir:
+            with span("train.checkpoint", workers=workers):
+                save_checkpoint(self.checkpoint_dir, params=self.params,
+                                opt_state=self.opt_state, step=self.step)
+
     def restore(self) -> bool:
         if not self.checkpoint_dir:
             return False
-        try:
-            params, opt, step, _ = load_checkpoint(self.checkpoint_dir)
-        except FileNotFoundError:
-            return False
-        self.params = jax.tree.map(jnp.asarray, params)
-        self.opt_state = jax.tree.map(jnp.asarray, opt)
+        with span("train.restore", workers=self.group.workers):
+            try:
+                params, opt, step, _ = load_checkpoint(self.checkpoint_dir)
+            except FileNotFoundError:
+                return False
+            self.params = jax.tree.map(jnp.asarray, params)
+            self.opt_state = jax.tree.map(jnp.asarray, opt)
         self.step = step
         self.restores += 1
         return True
