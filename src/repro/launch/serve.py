@@ -257,7 +257,9 @@ class ServingEngine:
         self.prefill_compile_count = 0
         self.aux_compile_count = 0      # zero-lane traces
         self._closure_fingerprint = self.closure_fingerprint()
-        self._decode = jax.jit(self._make_decode())
+        # the cache is donated: the step updates it in place, and the
+        # arrays passed in are deleted once it returns
+        self._decode = jax.jit(self._make_decode(), donate_argnums=(1,))
         self._prefill = jax.jit(self._make_prefill())
         self._zero = jax.jit(self._make_zero_lane())
 
@@ -272,16 +274,12 @@ class ServingEngine:
             # trace-time side effect: runs once per compile, not per call —
             # the same counting idiom as RingWorkerGroup.compile_count
             self.compile_count += 1
-            logits, new_cache = model.decode_step_lanes(params, cache,
-                                                        tokens, positions)
-            def keep(n, o):
-                mask = active.reshape((1, -1) + (1,) * (n.ndim - 2))
-                return jnp.where(mask, n, o).astype(o.dtype)
             # free lanes are *masked*, not resized: their garbage decode
             # never lands in the cache, and the shapes never change
-            new_cache = jax.tree.map(keep, new_cache, cache)
+            logits, cache = model.decode_step_lanes(params, cache, tokens,
+                                                    positions, active)
             nxt = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
-            return nxt, new_cache
+            return nxt, cache
 
         return step
 

@@ -223,6 +223,57 @@ def decode_attention(
     return out.reshape(b, sq, hq, d).astype(q.dtype)
 
 
+def decode_attention_lanes(
+    q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
+    k_new: jax.Array, v_new: jax.Array, seen: jax.Array,
+) -> jax.Array:
+    """One-token attention per lane over a read-only KV cache plus the
+    token's own key and value.
+
+    q, k_new, v_new: (B,1,H,D); caches: (B,S_cache,Hkv,D); seen: (B,S_cache)
+    bool, the cache slots each lane attends besides its new token. One
+    softmax spans both parts, so the result equals writing the new row into
+    the cache first and attending to it (:func:`decode_attention`) while
+    the cache itself is never written here.
+
+    The cache is read as it lies, as ``S_cache * Hkv`` rows of ``D`` per
+    lane (a reshape that moves nothing). A grouped einsum would batch over
+    kv heads, which sit between the slot and head_dim axes, and the TPU
+    compiler copies each layer's cache into a head-major layout to do that.
+    Here every query head scores every row and the rows of other kv heads
+    are masked out of the softmax, so their probabilities are exactly zero
+    in the value product: Hkv times the multiply-adds of the grouped form,
+    which decode can spare (reading the cache bounds it), no copy, and no
+    kv repeat.
+    """
+    b, _, hq, d = q.shape
+    sc, hkv = k_cache.shape[1:3]
+    g = hq // hkv
+    scale = 1.0 / math.sqrt(d)
+    own = (jnp.arange(hq) // g)[:, None] == jnp.arange(hkv)[None, :]  # (Hq,Hkv)
+    with jax.named_scope("flash_attention"):
+        qs = (q[:, 0].astype(jnp.float32) * scale).astype(q.dtype)  # (B,Hq,D)
+        s = jnp.einsum("bhd,brd->bhr", qs, k_cache.reshape(b, sc * hkv, d),
+                       preferred_element_type=jnp.float32)
+        mask = own[None, :, None, :] & seen[:, None, :, None]
+        s = jnp.where(mask.reshape(b, hq, sc * hkv), s, NEG_INF)
+        s_own = jnp.einsum("bjgd,bjd->bjg", qs.reshape(b, hkv, g, d),
+                           k_new[:, 0], preferred_element_type=jnp.float32
+                           ).reshape(b, hq)
+        m = jnp.maximum(s.max(axis=-1), s_own)
+        p = jnp.exp(s - m[..., None])
+        p_own = jnp.exp(s_own - m)
+        total = p.sum(axis=-1) + p_own
+        out = jnp.einsum("bhr,brd->bhd", (p / total[..., None]).astype(q.dtype),
+                         v_cache.reshape(b, sc * hkv, d),
+                         preferred_element_type=jnp.float32)
+        out += jnp.einsum("bjg,bjd->bjgd",
+                          (p_own / total).reshape(b, hkv, g).astype(q.dtype),
+                          v_new[:, 0], preferred_element_type=jnp.float32
+                          ).reshape(b, hq, d)
+    return out[:, None].astype(q.dtype)
+
+
 # ---------------------------------------------------------------------------
 # MLPs
 # ---------------------------------------------------------------------------

@@ -97,7 +97,7 @@ class BaseModel:
             jax.ShapeDtypeStruct((), jnp.int32))
         return jax.tree.map(lambda x, s: x.astype(s.dtype), cache, evolved)
 
-    def decode_step_lanes(self, params, cache, tokens, positions):
+    def decode_step_lanes(self, params, cache, tokens, positions, active):
         """Per-lane decode: every batch lane advances at its *own* position.
 
         ``decode_step`` takes one scalar ``cur_index`` shared by the whole
@@ -106,10 +106,14 @@ class BaseModel:
         This wrapper vmaps the family's own ``decode_step`` over the cache's
         batch axis (:data:`CACHE_BATCH_AXIS` — axis 1 of every leaf across
         all families), so each lane runs the unmodified single-request
-        semantics at its private position.
+        semantics at its private position. Recurrent state (SSM, conv, WKV)
+        is rewritten whole every token, so the new cache is selected whole:
+        an inactive lane (``active[b]`` false) keeps its old cache, and its
+        garbage decode never lands. ``DenseLM`` overrides this with a step
+        that writes only the new K/V rows.
 
-        tokens ``(B, 1)`` int32, positions ``(B,)`` int32 ->
-        (logits ``(B, 1, Vp)``, cache).
+        tokens ``(B, 1)`` int32, positions ``(B,)`` int32, active ``(B,)``
+        bool -> (logits ``(B, 1, Vp)``, cache).
         """
 
         def one(lane_cache, tok, pos):
@@ -119,10 +123,16 @@ class BaseModel:
             return logits[0], jax.tree.map(
                 lambda x: jnp.squeeze(x, CACHE_BATCH_AXIS), new_c)
 
-        return jax.vmap(
+        logits, new_cache = jax.vmap(
             one, in_axes=(CACHE_BATCH_AXIS, 0, 0),
             out_axes=(0, CACHE_BATCH_AXIS),
         )(cache, tokens, positions)
+
+        def keep(n, o):
+            mask = active.reshape((1, -1) + (1,) * (n.ndim - 2))
+            return jnp.where(mask, n, o).astype(o.dtype)
+
+        return logits, jax.tree.map(keep, new_cache, cache)
 
 
 # Every family lays its decode cache out as (layers, batch, ...): the batch

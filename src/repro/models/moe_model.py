@@ -112,3 +112,7 @@ class MoeLM(DenseLM):
         h = L.rms_norm(h, params["ln_f"])
         logits = masked_lm_head(h, params["lm_head"], cfg.vocab)
         return logits, {"k": new_k, "v": new_v}
+
+    # routing capacity is per call: lanes decoded together would compete
+    # for experts' capacity, so each lane decodes alone (the vmap path)
+    decode_step_lanes = BaseModel.decode_step_lanes

@@ -135,6 +135,25 @@ class DenseLM(BaseModel):
             "v": ParamSpec(shape, axes, dtype=dtype, init="zeros"),
         }
 
+    def _decode_layer(self, lp, h, positions, attend):
+        """One layer for one new token a lane. ``attend(q, k, v)`` reads
+        the layer's cache and returns (attention output, what the layer
+        scan emits)."""
+        cfg = self.cfg
+        x = L.rms_norm(h, lp["ln1"])
+        q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
+        if cfg.qk_norm:
+            q = L.rms_norm(q, lp["q_norm"])
+            k = L.rms_norm(k, lp["k_norm"])
+        q = L.apply_rope(q, positions, cfg.rope_theta)
+        k = L.apply_rope(k, positions, cfg.rope_theta)
+        o, out = attend(q, k, v)
+        h = h + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
+        x = L.rms_norm(h, lp["ln2"])
+        return h + L.swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"]), out
+
     def decode_step(self, params, cache, tokens, cur_index):
         """One token: update each layer's KV cache, return logits.
 
@@ -146,38 +165,74 @@ class DenseLM(BaseModel):
         positions = jnp.full((1,), cur_index, dtype=jnp.int32)
         sc = cache["k"].shape[2]
         write_at = cur_index % sc if cfg.sliding_window is not None else cur_index
+        # ring buffer: all slots valid once full; mask by recency
+        valid_to = (jnp.minimum(cur_index, sc - 1)
+                    if cfg.sliding_window is not None else cur_index)
 
         def body(h, xs):
             lp, k_cache, v_cache = xs
-            x = L.rms_norm(h, lp["ln1"])
-            q = jnp.einsum("bsd,dhk->bshk", x, lp["wq"])
-            k = jnp.einsum("bsd,dhk->bshk", x, lp["wk"])
-            v = jnp.einsum("bsd,dhk->bshk", x, lp["wv"])
-            if cfg.qk_norm:
-                q = L.rms_norm(q, lp["q_norm"])
-                k = L.rms_norm(k, lp["k_norm"])
-            q = L.apply_rope(q, positions, cfg.rope_theta)
-            k = L.apply_rope(k, positions, cfg.rope_theta)
-            k_cache = jax.lax.dynamic_update_slice(
-                k_cache, k.astype(k_cache.dtype), (0, write_at, 0, 0))
-            v_cache = jax.lax.dynamic_update_slice(
-                v_cache, v.astype(v_cache.dtype), (0, write_at, 0, 0))
-            if cfg.sliding_window is not None:
-                # ring buffer: all slots valid once full; mask by recency
-                o = L.decode_attention(q, k_cache, v_cache,
-                                       jnp.minimum(cur_index, sc - 1))
-            else:
-                o = L.decode_attention(q, k_cache, v_cache, cur_index)
-            h = h + jnp.einsum("bshk,hkd->bsd", o, lp["wo"])
-            x = L.rms_norm(h, lp["ln2"])
-            h = h + L.swiglu(x, lp["w_gate"], lp["w_up"], lp["w_down"])
-            return h, (k_cache, v_cache)
+
+            def attend(q, k, v):
+                kc = jax.lax.dynamic_update_slice(
+                    k_cache, k.astype(k_cache.dtype), (0, write_at, 0, 0))
+                vc = jax.lax.dynamic_update_slice(
+                    v_cache, v.astype(v_cache.dtype), (0, write_at, 0, 0))
+                return L.decode_attention(q, kc, vc, valid_to), (kc, vc)
+
+            return self._decode_layer(lp, h, positions, attend)
 
         h, (new_k, new_v) = jax.lax.scan(
             body, h, (params["blocks"], cache["k"], cache["v"]))
         h = L.rms_norm(h, params["ln_f"])
         logits = masked_lm_head(h, params["lm_head"], cfg.vocab)
         return logits, {"k": new_k, "v": new_v}
+
+    def decode_step_lanes(self, params, cache, tokens, positions, active):
+        """Per-lane decode that writes only each active lane's new K/V row.
+
+        Lane b decodes at its own ``positions[b]``. Each layer's cache is a
+        read-only scan input: attention takes the slots the lane has seen
+        plus the new token's own key and value in one softmax, which equals
+        :meth:`decode_step`'s write-then-attend. The scan emits only the new
+        rows, ``(layers, B, kv_heads, head_dim)``; one scatter after it puts
+        each active lane's row at its slot. A KV cache is append-only, so
+        that row is the whole update: with the cache donated the step reads
+        it once and writes ``layers x lanes`` rows in place, and an
+        inactive lane's cache stays bit-identical.
+
+        SWA: the row goes to slot ``position % cache_len``; the slot it
+        overwrites (the token leaving the window) is masked out.
+        """
+        cfg = self.cfg
+        h = params["embed"][tokens]  # (B, 1, D)
+        sc = cache["k"].shape[2]
+        kpos = jnp.arange(sc)[None, :]
+        seen = kpos < positions[:, None]
+        write_at = positions
+        if cfg.sliding_window is not None:
+            write_at = positions % sc
+            seen &= kpos != write_at[:, None]
+
+        def body(h, xs):
+            lp, k_cache, v_cache = xs
+
+            def attend(q, k, v):
+                k = k.astype(k_cache.dtype)
+                v = v.astype(v_cache.dtype)
+                o = L.decode_attention_lanes(q, k_cache, v_cache, k, v, seen)
+                return o, (k[:, 0], v[:, 0])
+
+            return self._decode_layer(lp, h, positions[:, None], attend)
+
+        h, rows = jax.lax.scan(
+            body, h, (params["blocks"], cache["k"], cache["v"]))
+        h = L.rms_norm(h, params["ln_f"])
+        logits = masked_lm_head(h, params["lm_head"], cfg.vocab)
+        lanes = jnp.arange(tokens.shape[0])
+        slot = jnp.where(active, write_at, sc)  # out of bounds: dropped
+        new = {name: cache[name].at[:, lanes, slot].set(row, mode="drop")
+               for name, row in zip(("k", "v"), rows)}
+        return logits, new
 
     def extra_input_specs(self, batch_size: int):
         if self.cfg.family == "vlm":

@@ -2,8 +2,9 @@
 
 The TPU compiler refuses what interpret mode accepts: misaligned tiles, 1-D
 blocks, programs that do not fit the chip. These tests compile the main
-path's Pallas wire kernels and the full-width one-chip train step for a
-described ``v5e:2x2`` and check what the compiler returns. Nothing runs.
+path's Pallas wire kernels, the full-width one-chip train step and the
+serving decode step for a described ``v5e:2x2`` and check what the compiler
+returns. Nothing runs.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and every test worker
@@ -11,6 +12,7 @@ imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -219,3 +221,40 @@ def test_ring_flatten_sits_behind_barriers(variant, topo, monkeypatch):
                              sharding=NamedSharding(mesh, P()))
     hlo = jax.jit(fn).lower(x).as_text()
     assert hlo.count("optimization_barrier") >= 2
+
+
+def test_full_width_decode_step_writes_only_new_rows(qwen3, one_chip):
+    """The serving engine's decode step at 16 lanes x 2048 (qwen3-0.6b,
+    bf16 weights), with the cache donated as the engine jits it: the new
+    cache is the donated one, temporaries stay under 5% of it, and no
+    select or copy carries the whole cache or one layer of it. Each step
+    then reads the cache once and writes only the live lanes' new rows."""
+    from repro.launch.serve import ServingEngine
+
+    lanes, max_seq = 16, 2048
+    engine = ServingEngine.__new__(ServingEngine)
+    engine.model = qwen3
+    engine.compile_count = engine.prefill_compile_count = 0
+
+    def shaped(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    cache = shaped(qwen3.abstract_cache(lanes, max_seq))
+    args = (shaped(qwen3.abstract_params(dtype=jnp.bfloat16)), cache,
+            *[jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in
+              (((lanes, 1), jnp.int32), ((lanes,), jnp.int32),
+               ((lanes,), jnp.bool_))])
+    compiled = jax.jit(engine._make_decode(),
+                       donate_argnums=(1,)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    cache_bytes = sum(x.size * x.dtype.itemsize
+                      for x in jax.tree.leaves(cache))
+    assert mem.alias_size_in_bytes >= cache_bytes
+    assert mem.temp_size_in_bytes < 0.05 * cache_bytes, (
+        f"{mem.temp_size_in_bytes / 2 ** 30:.2f} GiB of temporaries")
+    layer = ",".join(map(str, cache["k"].shape[1:]))
+    moved = re.findall(
+        rf"= bf16\[(?:\d+,)?{layer}\]\{{[^}}]*\}} (select|copy)\(",
+        compiled.as_text())
+    assert not moved, f"{len(moved)} cache-sized {sorted(set(moved))}"

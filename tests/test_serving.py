@@ -129,6 +129,32 @@ class TestContinuousBatching:
                 np.asarray(req.tokens), expect,
                 err_msg=f"request {req.id} diverged from solo generation")
 
+    @pytest.mark.parametrize("fix", ["dense", "hybrid"])
+    def test_decode_step_donates_the_cache(self, fix, request):
+        """Every decode step hands its cache to the compiled step, which
+        updates it in place: the arrays passed in are deleted once it
+        returns, at every occupancy, and the step compiles once."""
+        model, params = request.getfixturevalue(fix)
+        engine = ServingEngine(model, params, max_batch=3, max_seq=32,
+                               prefill_chunk=4)
+        rng = np.random.default_rng(5)
+        for i, max_new in enumerate((6, 8, 10)):
+            engine.submit(Request(id=i, prompt=rng.integers(
+                0, model.cfg.vocab, size=4 + i, dtype=np.int32),
+                max_new=max_new))
+        occupancies = set()
+        while not engine.idle:
+            engine.admit(limit=1)
+            before = jax.tree.leaves(engine.cache)
+            occupancies.add(int(engine.active.sum()))
+            engine.step()
+            assert all(x.is_deleted() for x in before)
+            assert not any(x.is_deleted()
+                           for x in jax.tree.leaves(engine.cache))
+        assert occupancies == {1, 2, 3}
+        assert engine.compile_count == 1
+        assert audit_serving_engine(engine) == []
+
     def test_eos_retires_and_lane_is_reused(self, dense):
         model, params = dense
         engine = ServingEngine(model, params, max_batch=1, max_seq=32,
